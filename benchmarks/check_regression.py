@@ -51,7 +51,9 @@ _REFERENCE_METRICS = {"loop_us", "single_us", "lexsort_us"}
 _REFERENCE_PREFIXES = ("phase_",)
 # derived / environment fields: not metrics, not identity (the _bytes /
 # _flops families are the static observability columns of compiled_cost)
-_IGNORED_EXACT = {"speedup", "ratio", "meps", "speedup_vs_1dev"} | _REFERENCE_METRICS
+_IGNORED_EXACT = {
+    "speedup", "ratio", "meps", "speedup_vs_1dev", "backend"
+} | _REFERENCE_METRICS
 _IGNORED_SUFFIX = (
     "_meps", "_bytes", "_bytes_per_dev", "_per_dev", "_ratio", "_flops"
 )

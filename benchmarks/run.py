@@ -62,6 +62,20 @@ def main(argv=None) -> int:
         return 0
 
     import importlib
+    import os
+    import re
+
+    import jax
+
+    # the persistent compile cache: $JAX_COMPILATION_CACHE_DIR when set,
+    # else one fixed directory in the checkout (a moving path never hits);
+    # source locations relative to the checkout, because a Pallas kernel's
+    # serialized body keeps them inside the cache key
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", os.path.join(root, ".jax_cache"))
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(root + os.sep))
 
     from benchmarks.common import emit, emit_json
 

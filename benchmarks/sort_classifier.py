@@ -170,7 +170,9 @@ def _u64_rows() -> list:
 
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.sort_classifier"],
-        env=dict(os.environ, JAX_ENABLE_X64="1", SORT_CLASSIFIER_U64="1"),
+        # CPU child (the parent may hold the chip); its rows say so
+        env=dict(os.environ, JAX_ENABLE_X64="1", SORT_CLASSIFIER_U64="1",
+                 JAX_PLATFORMS="cpu"),
         capture_output=True,
         text=True,
         timeout=1200,
@@ -178,7 +180,7 @@ def _u64_rows() -> list:
     if proc.returncode != 0:
         print(f"# u64 cell failed in subprocess:\n{proc.stderr[-2000:]}")
         return []
-    return _json.loads(proc.stdout.splitlines()[-1])
+    return [dict(r, backend="cpu") for r in _json.loads(proc.stdout.splitlines()[-1])]
 
 
 if __name__ == "__main__":

@@ -121,6 +121,7 @@ def run(quick: bool = False):
             res = _child(d, n, kind)
             rows.append({
                 "bench": "dist_multilevel",
+                "backend": "cpu",
                 "devices": d,
                 "mesh": "1-axis" if kind == "one" else "2-axis",
                 "n": n,
@@ -141,7 +142,10 @@ def run(quick: bool = False):
 
 
 def _child(d: int, n: int, kind: str, trace: str = "") -> dict:
-    env = {**os.environ, "PYTHONPATH": os.pathsep.join(sys.path)}
+    # a virtual-device CPU simulation: the child never touches the chip,
+    # which the parent process may hold
+    env = {**os.environ, "PYTHONPATH": os.pathsep.join(sys.path),
+           "JAX_PLATFORMS": "cpu"}
     r = subprocess.run(
         [sys.executable, "-c", _CHILD, str(d), str(n), kind, trace],
         capture_output=True, text=True, env=env, timeout=1200,

@@ -70,8 +70,10 @@ def run(quick: bool = False):
     counts = DEVICE_COUNTS[:3] if quick else DEVICE_COUNTS
     rows: list[Row] = []
     t1 = None
+    # virtual-device CPU simulations: the children never touch the chip,
+    # which the parent process may hold
     env = {**os.environ,
-           "PYTHONPATH": os.pathsep.join(sys.path)}
+           "PYTHONPATH": os.pathsep.join(sys.path), "JAX_PLATFORMS": "cpu"}
     for d in counts:
         r = subprocess.run(
             [sys.executable, "-c", _CHILD, str(d), str(n)],
@@ -83,7 +85,7 @@ def run(quick: bool = False):
         if t1 is None:
             t1 = res["t"]
         rows.append({
-            "bench": "scaling", "devices": d, "n": n,
+            "bench": "scaling", "backend": "cpu", "devices": d, "n": n,
             "s_per_call": round(res["t"], 5),
             "speedup_vs_1dev": round(t1 / res["t"], 2),
             "coll_bytes_per_dev": int(res["coll_bytes_per_dev"]),

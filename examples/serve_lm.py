@@ -29,7 +29,10 @@ def main() -> int:
     args = ap.parse_args()
 
     cfg = get_reduced(args.arch)
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = jax.make_mesh(
+        (len(jax.devices()), 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
     params = init_model(jax.random.PRNGKey(0), cfg)
 
     # scheduler: admit a ragged queue, batch by sorted remaining length
@@ -55,19 +58,19 @@ def main() -> int:
         prompts[r_i, -plen:] = rng.integers(0, cfg.vocab_size, plen)
     prompts = jnp.asarray(prompts)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         out1 = engine.generate(prompts, args.new)
     print(f"generated {out1.shape} tokens; first row: {np.asarray(out1[0,:8])}...")
 
     # determinism check (greedy): the SAME engine back-to-back — generate()
     # reinitializes the donated KV cache, so a second call can't attend
     # over the first call's stale keys/values
-    with mesh:
+    with jax.set_mesh(mesh):
         out2 = engine.generate(prompts, args.new)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
     # ... and across fresh engine instances
     engine2 = Engine(cfg, scfg, mesh, params)
-    with mesh:
+    with jax.set_mesh(mesh):
         out3 = engine2.generate(prompts, args.new)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out3))
     print("greedy decode deterministic across calls and engine instances — OK")

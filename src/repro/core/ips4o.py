@@ -286,6 +286,22 @@ def stable_full_sort(arrays: Any) -> Any:
     return jax.tree.map(lambda a: jnp.take(a, order, axis=0), arrays)
 
 
+def replicated(tree: Any) -> Any:
+    """Reshard every leaf whose type carries an Explicit-axis sharding
+    (``jax.make_mesh``'s default) to replicated.  The engine pads, scatters
+    and gathers across whole arrays, which sharding-in-types cannot resolve
+    for a sharded operand; on a one-device mesh no data moves.  Unsharded
+    leaves pass through untouched."""
+
+    def one(a):
+        s = jax.typeof(a).sharding
+        if all(p is None for p in s.spec):
+            return a
+        return jax.sharding.reshard(a, s.update(spec=jax.sharding.PartitionSpec()))
+
+    return jax.tree.map(one, tree)
+
+
 def pad_with_sentinel(arrays: Any, unit: int) -> Any:
     """Pad every leaf of the arrays dict to a multiple of ``unit``; pad keys
     get the dtype sentinel so they sort to the tail (the overflow-block
@@ -901,6 +917,7 @@ def ips4o_sort(
     arrays = {"k": keys}
     if values is not None:
         arrays["v"] = values
+    arrays = replicated(arrays)
 
     unit = max(cfg.base_case, cfg.tile)
     with obs.trace(

@@ -52,7 +52,6 @@ __all__ = [
     "stable_partition",
     "batched_stable_partition",
     "partition_permutation",
-    "partition_ranks_pallas",
     "partition_blocks",
     "ENGINES",
 ]
@@ -126,28 +125,6 @@ def partition_permutation(
         jnp.zeros((n,), jnp.int32).at[dest.reshape(-1)].set(src, mode="promise_in_bounds")
     )
     return perm, offsets
-
-
-def partition_ranks_pallas(
-    bucket: jax.Array,
-    offsets: jax.Array,
-    nb: int,
-    *,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Per-element stable counting destination via the Pallas rank kernel.
-
-    ``offsets`` is the (nb+1,) bucket-boundary array (only the exclusive
-    prefix ``offsets[:-1]`` is consumed).  Returns dest (n,) int32 such that
-    scattering ``a[i] -> dest[i]`` reproduces the stable partition.
-    """
-    from repro.kernels.dispatch_rank import partition_ranks
-
-    if interpret is None:
-        interpret = _default_interpret()
-    return partition_ranks(
-        bucket.astype(jnp.int32), offsets[:-1], nb=nb, interpret=interpret
-    )
 
 
 def stable_partition(

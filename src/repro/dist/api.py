@@ -29,7 +29,6 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
@@ -288,8 +287,8 @@ def sort(
             out, m, o = body({"k": k})
             return out["k"], m, o
 
-        f = shard_map(run, mesh=mesh, in_specs=(spec,),
-                      out_specs=(spec, spec, spec), check_rep=False)
+        f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(spec,),
+                                  out_specs=(spec, spec, spec), check_vma=False))
         with span:
             out_k, counts, ovf = f(enc)
         return keyspace.decode(out_k, keys.dtype), counts, ovf
@@ -300,11 +299,13 @@ def sort(
         out, m, o = body({"k": k, "v": v})
         return out["k"], out["v"], m, o
 
-    # check_rep=False throughout: the replication checker cannot see
+    # check_vma=False throughout: the replication checker cannot see
     # through the engine's scan-shaped internals (jax's own recommendation
-    # for this false positive); no output here claims replication anyway
-    f = shard_map(run, mesh=mesh, in_specs=(spec, vspecs),
-                  out_specs=(spec, vspecs, spec, spec), check_rep=False)
+    # for this false positive); no output here claims replication anyway.
+    # jit throughout too: called eagerly on Explicit mesh axes, a shard_map
+    # body with a lax.cond fails XLA's sharding check
+    f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(spec, vspecs),
+                              out_specs=(spec, vspecs, spec, spec), check_vma=False))
     with span:
         out_k, out_v, counts, ovf = f(enc, values)
     return keyspace.decode(out_k, keys.dtype), out_v, counts, ovf
@@ -368,8 +369,8 @@ def argsort(
         out, m, o = body({"k": k, "v": gidx})
         return out["v"], m, o
 
-    f = shard_map(run, mesh=mesh, in_specs=(spec,),
-                  out_specs=(spec, spec, spec), check_rep=False)
+    f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(spec,),
+                              out_specs=(spec, spec, spec), check_vma=False))
     return f(keyspace.encode(keys))
 
 
@@ -466,10 +467,10 @@ def _rank_k(
         return fin_v, jnp.take(cand_i, fin_i, axis=0)
 
     # outputs are replicated: every shard computes the same finish over the
-    # same gathered candidates (check_rep can't see through the partial
+    # same gathered candidates (check_vma can't see through the partial
     # sort's internals, so it is disabled rather than trusted to infer)
-    f = shard_map(run, mesh=mesh, in_specs=(P(ax),), out_specs=(P(), P()),
-                  check_rep=False)
+    f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(P(ax),),
+                              out_specs=(P(), P()), check_vma=False))
     out_v, out_i = f(enc)
     if largest:
         out_v = ~out_v
@@ -525,7 +526,7 @@ def group_by(
         starts = valid & ((pos == 0) | (ek != prev))
         return starts
 
-    f = shard_map(run, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P(ax))
+    f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P(ax)))
     starts = f(out_k, counts)
     if values is None:
         return out_k, starts, counts, ovf

@@ -47,7 +47,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -222,10 +221,10 @@ def sort_elastic(
     ):
         if not resumed:
             aspec = _leaf_specs(arrays, ax)
-            init = shard_map(
+            init = jax.shard_map(
                 lambda t: _pre_exchange(t, n_local, ax, d) if d > 1 else t,
                 mesh=mesh, in_specs=(aspec,), out_specs=aspec,
-                check_rep=False,
+                check_vma=False,
             )
             arrays = jax.jit(init)(arrays)
             m = jnp.full((d,), n_local, jnp.int32)
@@ -248,10 +247,10 @@ def sort_elastic(
 
             in_a = _leaf_specs(arrays, ax)
             out_like = _arrays_like(level.n_out)
-            f = shard_map(
+            f = jax.shard_map(
                 step, mesh=mesh, in_specs=(in_a, P(ax)),
                 out_specs=(_leaf_specs(out_like, ax), P(ax), P(ax)),
-                check_rep=False,
+                check_vma=False,
             )
             arrays, m, ovf_i = jax.jit(f)(arrays, m)
             ovf = jnp.logical_or(ovf, ovf_i)
@@ -259,10 +258,10 @@ def sort_elastic(
             _save(i + 1)
 
         aspec = _leaf_specs(arrays, ax)
-        fin = shard_map(
+        fin = jax.shard_map(
             lambda t, mm: _finish_local(t, mm[0], cfg_run, eng),
             mesh=mesh, in_specs=(aspec, P(ax)), out_specs=aspec,
-            check_rep=False,
+            check_vma=False,
         )
         out = jax.jit(fin)(arrays, m)
     manager.wait()

@@ -15,9 +15,10 @@ automatically — which is what lets IPS4o's overlapped-window base case fix
 bucket-straddling tiles (DESIGN.md §4.3).  A payload index rides along so
 the wrapper can permute arbitrary payload pytrees.
 
-Each compare-exchange round at distance d is expressed as a static reshape
-(W,) -> (W/2d, 2, d) so partners (idx XOR d) sit in adjacent sub-rows; the
-direction bit (idx AND 2*size) is constant per sub-row.  All shapes static.
+Each compare-exchange round at distance d fetches every lane's partner
+(idx XOR d) with two lane rotations of the (8, W) block and keeps the
+minimum or maximum by the direction bit (idx AND 2*size).  All shapes
+static.
 """
 from __future__ import annotations
 
@@ -28,44 +29,46 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 
 __all__ = ["bitonic_sort_windows"]
 
 
-def _cmp_exchange(b, k, v, size: int, d: int, W: int):
-    """One bitonic round: partner = idx ^ d, ascending iff (idx & 2*size)==0."""
-    shape = (W // (2 * d), 2, d)
-    b3, k3, v3 = (x.reshape(shape) for x in (b, k, v))
-    lo = (b3[:, 0], k3[:, 0], v3[:, 0])
-    hi = (b3[:, 1], k3[:, 1], v3[:, 1])
-    # ascending iff (base_idx & (2*size)) == 0; base_idx = row * 2d.
-    row = jax.lax.broadcasted_iota(jnp.int32, (W // (2 * d), 1), 0)
-    asc = ((row * (2 * d)) & (2 * size)) == 0
-    # lexicographic (bucket, key) greater-than
-    gt = (lo[0] > hi[0]) | ((lo[0] == hi[0]) & (lo[1] > hi[1]))
-    swap = jnp.where(asc, gt, ~gt)
-    out = []
-    for a, c in zip(lo, hi):
-        na = jnp.where(swap, c, a)
-        nc = jnp.where(swap, a, c)
-        out.append(jnp.stack([na, nc], axis=1).reshape(W))
-    (b, k, v) = out
-    return b, k, v
+# windows per grid step: one (8, W) block, the 32-bit sublane tile
+_ROWS = 8
+
+
+def _cmp_exchange(b, k, v, lane, size: int, d: int, W: int):
+    """One bitonic round on (rows, W) windows: partner = lane ^ d, fetched
+    with two lane rotations (the rotated lane index picks the one that
+    holds the partner, so the sign convention does not matter); runs are
+    ascending iff (lane & 2*size) == 0."""
+    roll = lambda x, sh: pltpu.roll(x, sh, 1)
+    fwd = roll(lane, d) == (lane ^ d)
+    bp, kp, vp = (jnp.where(fwd, roll(x, d), roll(x, W - d)) for x in (b, k, v))
+    # lexicographic (bucket, key) order; equal pairs never move, so the
+    # payload of a tie stays put on both lanes
+    p_less = (bp < b) | ((bp == b) & (kp < k))
+    p_more = (bp > b) | ((bp == b) & (kp > k))
+    # the lower lane of an ascending pair keeps the min, as does the upper
+    # lane of a descending one
+    want_min = ((lane & (2 * size)) != 0) == ((lane & d) != 0)
+    take = (want_min & p_less) | (jnp.logical_not(want_min) & p_more)
+    return tuple(jnp.where(take, xp, x) for x, xp in ((b, bp), (k, kp), (v, vp)))
 
 
 def _kernel(b_ref, k_ref, v_ref, bo_ref, ko_ref, vo_ref, *, W: int):
-    b = b_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
+    b, k, v = b_ref[...], k_ref[...], v_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
     for s in range(int(math.log2(W))):
         size = 1 << s  # ascending runs of length 2*size after this stage
         for dp in range(s, -1, -1):
-            b, k, v = _cmp_exchange(b, k, v, size, 1 << dp, W)
-    bo_ref[0] = b
-    ko_ref[0] = k
-    vo_ref[0] = v
+            b, k, v = _cmp_exchange(b, k, v, lane, size, 1 << dp, W)
+    bo_ref[...] = b
+    ko_ref[...] = k
+    vo_ref[...] = v
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -78,8 +81,9 @@ def bitonic_sort_windows(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Sort each window (row) of (num_w, W) arrays by (bucket, key).
 
-    W must be a power of two.  Returns permuted (bucket, keys, idx).
-    VMEM per grid step: 3 arrays * W * 4 B (W=8192 -> 96 KiB).
+    W must be a power of two (a multiple of 128 on the chip).  Returns
+    permuted (bucket, keys, idx).  Windows go eight per grid step; VMEM
+    per step: 3 arrays * 8 * W * 4 B (W=8192 -> 768 KiB).
     ``interpret=None`` resolves through the shared off-TPU policy
     (``kernels.resolve_interpret``).
     """
@@ -87,17 +91,16 @@ def bitonic_sort_windows(
     num_w, W = keys.shape
     if W & (W - 1):
         raise ValueError(f"W={W} must be a power of two")
-    spec = lambda: pl.BlockSpec((1, W), lambda i: (i, 0))
-    shapes = [
-        jax.ShapeDtypeStruct((num_w, W), bucket.dtype),
-        jax.ShapeDtypeStruct((num_w, W), keys.dtype),
-        jax.ShapeDtypeStruct((num_w, W), idx.dtype),
-    ]
-    return pl.pallas_call(
+    rows = -(-num_w // _ROWS) * _ROWS  # pad windows sort among themselves
+    pad = lambda x: jnp.pad(x, ((0, rows - num_w), (0, 0)))
+    spec = lambda: pl.BlockSpec((_ROWS, W), lambda i: (i, 0))
+    shapes = [jax.ShapeDtypeStruct((rows, W), x.dtype) for x in (bucket, keys, idx)]
+    out = pl.pallas_call(
         functools.partial(_kernel, W=W),
-        grid=(num_w,),
+        grid=(rows // _ROWS,),
         in_specs=[spec(), spec(), spec()],
         out_specs=[spec(), spec(), spec()],
         out_shape=shapes,
         interpret=interpret,
-    )(bucket, keys, idx)
+    )(pad(bucket), pad(keys), pad(idx))
+    return tuple(x[:num_w] for x in out)

@@ -34,11 +34,14 @@ re-attaches the r tail elements outside the grid.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 __all__ = ["permute_blocks_by_dest", "stable_block_dest"]
 
@@ -89,9 +92,10 @@ def _kernel(dst_ref, a_in, a_out, visited, st_ref, swap0, swap1, sem,
         @pl.when(st_ref[S_FILLED] == 0)
         def _scan():
             vi = visited[...]  # (1, nblocks)
-            # first unvisited slot, vectorized (0 < 1 so argmin = first 0)
-            head = jnp.argmin(vi, axis=1)[0].astype(jnp.int32)
-            found = jnp.min(vi) == 0
+            # first unvisited slot, vectorized: the least lane whose bit is
+            # 0 (an int min-reduction; Mosaic's argmin takes only float32)
+            head = jnp.min(jnp.where(vi == 0, lane, nblocks))
+            found = head < nblocks
 
             @pl.when(found)
             def _read():
@@ -147,7 +151,7 @@ def permute_blocks_by_dest(
     dst: jax.Array,
     *,
     block_elems: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Move block i of ``a`` to slot dst[i], HBM-in-place.
 
@@ -164,6 +168,7 @@ def permute_blocks_by_dest(
     Returns the permuted array (same HBM buffer for the aligned prefix:
     input is aliased/donated).
     """
+    interpret = resolve_interpret(interpret)
     if block_elems % LANES:
         raise ValueError("block_elems must be a multiple of 128")
     brows = block_elems // LANES

@@ -53,8 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.classify.radix import radix_bucket_ids
-from repro.core.sampling import sentinel_for
 from repro.kernels import resolve_interpret
+from repro.kernels.level_fused import classify_tile, splitter_block, tile_histogram
 
 __all__ = [
     "classify_histogram",
@@ -77,23 +77,13 @@ def default_rows(n: int, key_bytes: int, k: int) -> int:
     return launch_spec("classify", key_bytes, k, n=n).rows
 
 
-def _kernel(keys_ref, spl_ref, bucket_ref, hist_ref, *, k: int, nb: int):
-    keys = keys_ref[...]  # (rows, 128)
-    spl = spl_ref[...]  # (1, k): k-1 splitters + the dtype sentinel
-    kf = keys[:, :, None]  # (rows, 128, 1)
-    sf = spl[0][None, None, :]  # (1, 1, k)
-    # j counts only the k-1 real splitters (a key above the sentinel, e.g.
-    # +inf, must still land in bucket k-1); eq compares against all k uppers.
-    # dtype= pins the accumulator: with x64 enabled (u64 keys) jnp.sum
-    # would otherwise widen int32 to int64 and mismatch the output refs
-    j = jnp.sum((kf > sf[..., : k - 1]).astype(jnp.int32), axis=-1, dtype=jnp.int32)
-    eq = jnp.any(kf == sf, axis=-1).astype(jnp.int32)
-    bucket = 2 * j + eq
-    bucket_ref[...] = bucket
-    # Fused per-tile histogram: one-hot reduce over the tile.
-    ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nb), 2)
-    onehot = (bucket[:, :, None] == ids).astype(jnp.int32)
-    hist_ref[...] = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)[None, :]
+def _kernel(keys_ref, spl_ref, bucket_ref, hist_ref, *, k: int, nb: int, rows: int):
+    # the level kernel's tile classifier (one splitter compare per step)
+    bucket_ref[...] = classify_tile(
+        keys_ref[...], spl_ref, k=k, classifier="tree", consumed=0
+    )
+    # Fused per-tile histogram: one-hot counts over the tile.
+    hist_ref[0] = tile_histogram(bucket_ref, rows, nb)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "rows", "interpret"))
@@ -121,32 +111,27 @@ def classify_histogram(
     num_tiles = n // tile
     nb = 2 * k
     keys2 = keys.reshape(num_tiles * rows, LANES)
-    # Append the dtype sentinel as the upper splitter of the last bucket: it
+    # The dtype sentinel is the upper splitter of the last bucket: it
     # never changes j (no key is > it) but keys *equal* to it get eq = 1 and
     # land in equality bucket 2(k-1)+1, matching the tree classifier.
-    upper = jnp.concatenate(
-        [splitters, jnp.full((1,), sentinel_for(splitters.dtype), splitters.dtype)]
-    )
-    spl2 = upper.reshape(1, k)
-
     bucket, hist = pl.pallas_call(
-        functools.partial(_kernel, k=k, nb=nb),
+        functools.partial(_kernel, k=k, nb=nb, rows=rows),
         grid=(num_tiles,),
         in_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
+            pl.BlockSpec((1, k, LANES), lambda i: (0, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, nb), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, nb), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, nb), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
-    )(keys2, spl2)
-    return bucket.reshape(n), hist
+    )(keys2, splitter_block(splitters, k))
+    return bucket.reshape(n), hist.reshape(num_tiles, nb)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "rows", "interpret"))
@@ -176,44 +161,34 @@ def classify_histogram_batched(
     num_tiles = n // tile
     nb = 2 * k
     keys2 = keys.reshape(B * num_tiles * rows, LANES)
-    upper = jnp.concatenate(
-        [
-            splitters,
-            jnp.full((B, 1), sentinel_for(splitters.dtype), splitters.dtype),
-        ],
-        axis=1,
-    )  # (B, k): per-row splitters + the dtype sentinel upper
-
     bucket, hist = pl.pallas_call(
-        functools.partial(_kernel, k=k, nb=nb),
+        functools.partial(_kernel, k=k, nb=nb, rows=rows),
         grid=(B, num_tiles),
         in_specs=[
             pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-            pl.BlockSpec((1, k), lambda b, i: (b, 0)),
+            # per-row splitters + the dtype sentinel upper
+            pl.BlockSpec((1, k, LANES), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-            pl.BlockSpec((1, nb), lambda b, i: (b * num_tiles + i, 0)),
+            pl.BlockSpec((1, 1, nb), lambda b, i: (b * num_tiles + i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((B * num_tiles, nb), jnp.int32),
+            jax.ShapeDtypeStruct((B * num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
-    )(keys2, upper)
+    )(keys2, splitter_block(splitters, k))
     return bucket.reshape(B, n), hist.reshape(B, num_tiles, nb)
 
 
-def _radix_kernel(keys_ref, bucket_ref, hist_ref, *, k: int, nb: int, consumed: int):
+def _radix_kernel(keys_ref, bucket_ref, hist_ref, *, k: int, nb: int, consumed: int,
+                  rows: int):
     # the extractor is elementwise (one shift + one mask — the IPS2Ra
     # classifier), so the id computation is shared verbatim with the XLA
     # engine: repro.classify.radix is the single source of truth
-    bucket = radix_bucket_ids(keys_ref[...], k, consumed)  # (rows, 128)
-    bucket_ref[...] = bucket
-    ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nb), 2)
-    onehot = (bucket[:, :, None] == ids).astype(jnp.int32)
-    # dtype= pins the x64-mode accumulator to the int32 output ref
-    hist_ref[...] = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)[None, :]
+    bucket_ref[...] = radix_bucket_ids(keys_ref[...], k, consumed)  # (rows, 128)
+    hist_ref[0] = tile_histogram(bucket_ref, rows, nb)
 
 
 @functools.partial(
@@ -249,20 +224,22 @@ def radix_histogram(
     keys2 = keys.reshape(num_tiles * rows, LANES)
 
     bucket, hist = pl.pallas_call(
-        functools.partial(_radix_kernel, k=k, nb=nb, consumed=consumed_bits),
+        functools.partial(
+            _radix_kernel, k=k, nb=nb, consumed=consumed_bits, rows=rows
+        ),
         grid=(num_tiles,),
         in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, nb), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, nb), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, nb), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
     )(keys2)
-    return bucket.reshape(n), hist
+    return bucket.reshape(n), hist.reshape(num_tiles, nb)
 
 
 @functools.partial(

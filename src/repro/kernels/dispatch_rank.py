@@ -12,20 +12,22 @@ The cross-tile running counters persist across the sequential TPU grid —
 the same "running bucket pointers on one core" idea as the block
 permutation kernel (§4.2), at element granularity.
 
-Two variants:
+One kernel body serves every variant.  The counters are a VMEM
+(nbp, 128) block — one sublane per bucket, every lane equal — set from
+``start`` at the first tile and advanced tile by tile by the level
+kernel's row-ranking helper (``kernels.level_fused.rank_rows``: one MXU
+prefix per 128-lane row, so nothing unrolls over the buckets).
 
-  * ``dispatch_ranks``: E small (MoE experts) — counters are SMEM scalars,
-    the per-bucket base lookup is an unrolled scalar loop.
+  * ``dispatch_ranks``: MoE experts (``ops.group_by(method="pallas")``);
+    n must be a multiple of the tile.
   * ``partition_ranks``: nb up to hundreds of buckets (the sort hot path's
-    2k+1) — counters are a VMEM (1, nb) vector and the base lookup is a
-    one-hot contraction, so nothing unrolls over nb.  Formerly the
-    "pallas" partition engine of ``core.partition.stable_partition``; the
-    fused level kernel (``kernels.level_fused``, DESIGN.md §10) demoted it
-    to the MoE dispatch engine and a sequential-counter oracle — its
-    running counters serialize the grid, where the fused kernel's
-    tile-local ranks + prefix epilogue do not.
+    2k+1), self-padding to the tile.  Formerly the "pallas" partition
+    engine of ``core.partition.stable_partition``; the fused level kernel
+    (``kernels.level_fused``, DESIGN.md §10) demoted it to a
+    sequential-counter oracle — its running counters serialize the grid,
+    where the fused kernel's tile-local ranks + prefix epilogue do not.
 
-``partition_ranks_batched`` (DESIGN.md §6) lifts the second variant over a
+``partition_ranks_batched`` (DESIGN.md §6) lifts the kernel over a
 leading batch dimension with a *batch grid dimension*: grid =
 (B, num_tiles).  The TPU grid iterates sequentially, minor dimension last,
 so the running counters simply reset at tile 0 of every row (instead of
@@ -43,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
+from repro.kernels.level_fused import padded_buckets, rank_rows
 
 __all__ = ["dispatch_ranks", "partition_ranks", "partition_ranks_batched"]
 
@@ -57,30 +60,40 @@ def _default_rank_rows(nb: int) -> int:
     return launch_spec("rank", 4, nb).rows or 8
 
 
-def _kernel(start_ref, eid_ref, dest_ref, run_ref, *, num_experts: int, rows: int):
-    pid = pl.program_id(0)
-
-    @pl.when(pid == 0)
+def _rank_kernel(start_ref, bid_ref, dest_ref, run_ref, *, rows: int):
+    # the minor grid dim walks the tiles of one row: counters restart at
+    # each row's first tile
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        for e in range(num_experts):
-            run_ref[e] = 0
+        run_ref[...] = start_ref[0]
 
-    eid = eid_ref[...]  # (rows, 128)
-    flat = eid.reshape(rows * LANES, 1)
-    ids = jax.lax.broadcasted_iota(jnp.int32, (1, num_experts), 1)
-    onehot = (flat == ids).astype(jnp.int32)  # (tile, E)
-    excl = jnp.cumsum(onehot, axis=0) - onehot  # rank within tile
-    rank_in_tile = jnp.sum(excl * onehot, axis=1)  # (tile,)
-    tile_hist = jnp.sum(onehot, axis=0)  # (E,)
+    run_ref[...] = rank_rows(bid_ref, dest_ref, rows, run_ref.shape[0], run_ref[...])
 
-    base = jnp.zeros((rows * LANES,), jnp.int32)
-    for e in range(num_experts):  # SMEM scalar reads, unrolled (E is small)
-        sel = flat[:, 0] == e
-        base = jnp.where(sel, start_ref[e] + run_ref[e], base)
-    dest_ref[...] = (base + rank_in_tile).reshape(rows, LANES)
 
-    for e in range(num_experts):
-        run_ref[e] = run_ref[e] + tile_hist[e]
+def _counting_dest(bucket: jax.Array, start: jax.Array, nb: int, rows: int,
+                   interpret: bool) -> jax.Array:
+    """(B, n_pad) ids, (B, nb) starts -> (B, n_pad) stable destinations;
+    n_pad a multiple of the rows*128 tile."""
+    B, n_pad = bucket.shape
+    nbp = padded_buckets(nb)
+    num_tiles = n_pad // (rows * LANES)
+    start = jnp.pad(start.astype(jnp.int32), ((0, 0), (0, nbp - nb)))
+    start = jnp.broadcast_to(start[:, :, None], (B, nbp, LANES))
+    bid2 = bucket.reshape(B * n_pad // LANES, LANES)
+    tile = lambda b, i: (b * num_tiles + i, 0)
+    dest = pl.pallas_call(
+        functools.partial(_rank_kernel, rows=rows),
+        grid=(B, num_tiles),
+        in_specs=[
+            pl.BlockSpec((1, nbp, LANES), lambda b, i: (b, 0, 0)),  # starts
+            pl.BlockSpec((rows, LANES), tile),
+        ],
+        out_specs=pl.BlockSpec((rows, LANES), tile),
+        out_shape=jax.ShapeDtypeStruct(bid2.shape, jnp.int32),
+        scratch_shapes=[pltpu.VMEM((nbp, LANES), jnp.int32)],  # running counters
+        interpret=interpret,
+    )(start, bid2)
+    return dest.reshape(B, n_pad)
 
 
 @functools.partial(jax.jit, static_argnames=("num_experts", "rows", "interpret"))
@@ -109,45 +122,20 @@ def dispatch_ranks(
         from repro.launch.roofline import launch_spec
 
         rows = launch_spec("rank", 4, num_experts, n=n).rows or 8
-    tile = rows * LANES
-    if n % tile:
-        raise ValueError(f"n={n} not a multiple of tile={tile}")
-    num_tiles = n // tile
-    eid2 = expert_id.reshape(num_tiles * rows, LANES)
-
-    dest = pl.pallas_call(
-        functools.partial(_kernel, num_experts=num_experts, rows=rows),
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # expert_start
-            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(eid2.shape, jnp.int32),
-        scratch_shapes=[pltpu.SMEM((num_experts,), jnp.int32)],
-        interpret=interpret,
-    )(expert_start, eid2)
-    return dest.reshape(n)
+    if n % (rows * LANES):
+        raise ValueError(f"n={n} not a multiple of tile={rows * LANES}")
+    return _counting_dest(
+        expert_id.astype(jnp.int32)[None], expert_start[None], num_experts,
+        rows, interpret,
+    )[0]
 
 
-def _rank_kernel(start_ref, bid_ref, dest_ref, run_ref, *, nb: int, rows: int):
-    pid = pl.program_id(0)
-
-    @pl.when(pid == 0)
-    def _init():
-        run_ref[...] = jnp.zeros((1, nb), jnp.int32)
-
-    bid = bid_ref[...]  # (rows, 128)
-    flat = bid.reshape(rows * LANES, 1)
-    ids = jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1)
-    onehot = (flat == ids).astype(jnp.int32)  # (tile, nb)
-    # dtype= pins the accumulators: with x64 enabled (u64 keys) the
-    # reductions would widen int32 to int64 and mismatch the int32 refs
-    excl = jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - onehot
-    rank_in_tile = jnp.sum(excl * onehot, axis=1, dtype=jnp.int32)  # (tile,)
-    base = jnp.sum(onehot * (start_ref[...] + run_ref[...]), axis=1, dtype=jnp.int32)
-    dest_ref[...] = (base + rank_in_tile).reshape(rows, LANES)
-    run_ref[...] = run_ref[...] + jnp.sum(onehot, axis=0, dtype=jnp.int32)[None, :]
+def _pad_to_tile(bucket: jax.Array, nb: int, tile: int) -> jax.Array:
+    """Pad axis 1 of (B, n) ids to the kernel tile with the trash id nb."""
+    n = bucket.shape[1]
+    n_pad = -(-n // tile) * tile
+    return jnp.pad(bucket.astype(jnp.int32), ((0, 0), (0, n_pad - n)),
+                   constant_values=nb)
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "rows", "interpret"))
@@ -163,8 +151,8 @@ def partition_ranks(
 
     Args:
       bucket: (n,) int32 bucket ids; ids outside [0, nb) are ignored (their
-        dest is unspecified and they never touch the running counters — the
-        wrapper layers use id ``nb`` as alignment padding).
+        dest is unspecified and they never touch a real bucket's counter —
+        the wrapper layers use id ``nb`` as alignment padding).
       start: (nb,) int32 exclusive prefix of bucket counts.
       nb: number of buckets (static).
       rows: tile rows; None derives the unified launch spec's candidate
@@ -178,46 +166,8 @@ def partition_ranks(
     n = bucket.shape[0]
     if rows is None:
         rows = _default_rank_rows(nb)
-    tile = rows * LANES
-    n_pad = -(-n // tile) * tile
-    if n_pad != n:  # align to the kernel tile; pads use the out-of-range id
-        bucket = jnp.concatenate(
-            [bucket, jnp.full((n_pad - n,), nb, jnp.int32)]
-        )
-    bid2 = bucket.reshape(n_pad // LANES, LANES)
-    num_tiles = n_pad // tile
-
-    dest = pl.pallas_call(
-        functools.partial(_rank_kernel, nb=nb, rows=rows),
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, nb), lambda i: (0, 0)),  # start
-            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(bid2.shape, jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, nb), jnp.int32)],  # running counters
-        interpret=interpret,
-    )(start.reshape(1, nb), bid2)
-    return dest.reshape(n_pad)[:n]
-
-
-def _rank_kernel_batched(start_ref, bid_ref, dest_ref, run_ref, *, nb: int, rows: int):
-    tile_id = pl.program_id(1)  # minor grid dim: tiles within the row
-
-    @pl.when(tile_id == 0)
-    def _init():  # new row: counters restart (rows are independent)
-        run_ref[...] = jnp.zeros((1, nb), jnp.int32)
-
-    bid = bid_ref[...]  # (rows, 128)
-    flat = bid.reshape(rows * LANES, 1)
-    ids = jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1)
-    onehot = (flat == ids).astype(jnp.int32)  # (tile, nb)
-    excl = jnp.cumsum(onehot, axis=0) - onehot
-    rank_in_tile = jnp.sum(excl * onehot, axis=1)
-    base = jnp.sum(onehot * (start_ref[...] + run_ref[...]), axis=1)
-    dest_ref[...] = (base + rank_in_tile).reshape(rows, LANES)
-    run_ref[...] = run_ref[...] + jnp.sum(onehot, axis=0)[None, :]
+    bid = _pad_to_tile(bucket[None], nb, rows * LANES)
+    return _counting_dest(bid, start[None], nb, rows, interpret)[0, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "rows", "interpret"))
@@ -243,28 +193,8 @@ def partition_ranks_batched(
     by one kernel, counters resetting at each row's first tile.
     """
     interpret = resolve_interpret(interpret)
-    B, n = bucket.shape
+    n = bucket.shape[1]
     if rows is None:
         rows = _default_rank_rows(nb)
-    tile = rows * LANES
-    n_pad = -(-n // tile) * tile
-    if n_pad != n:  # align rows to the kernel tile; pads use the trash id
-        bucket = jnp.concatenate(
-            [bucket, jnp.full((B, n_pad - n), nb, jnp.int32)], axis=1
-        )
-    bid2 = bucket.reshape(B * n_pad // LANES, LANES)
-    num_tiles = n_pad // tile
-
-    dest = pl.pallas_call(
-        functools.partial(_rank_kernel_batched, nb=nb, rows=rows),
-        grid=(B, num_tiles),
-        in_specs=[
-            pl.BlockSpec((1, nb), lambda b, i: (b, 0)),  # per-row starts
-            pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-        out_shape=jax.ShapeDtypeStruct(bid2.shape, jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, nb), jnp.int32)],  # running counters
-        interpret=interpret,
-    )(start.reshape(B, nb), bid2)
-    return dest.reshape(B, n_pad)[:, :n]
+    bid = _pad_to_tile(bucket, nb, rows * LANES)
+    return _counting_dest(bid, start, nb, rows, interpret)[:, :n]

@@ -30,10 +30,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 __all__ = ["flash_attention"]
 
@@ -63,12 +66,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, seq: int,
 
     def body(j, carry):
         m, l, acc = carry
-        # leading axis via a 1-sized dslice: a bare int index has no
-        # interpret-mode load-discharge rule in this jax version
-        kb = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk), slice(None))
-                     )[0].astype(jnp.float32)  # (bk, hd)
-        vb = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk), slice(None))
-                     )[0].astype(jnp.float32)
+        kb = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # (bk, hd)
+        vb = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())))  # (bq, bk)
         rows = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -108,8 +107,9 @@ def flash_attention(
     window: int = 0,
     bq: int = 512,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     b, h, s, hd = q.shape
     bq = min(bq, s)
     bk = min(bk, s)

@@ -20,12 +20,15 @@ Per-step VMEM: k,v blocks (bt × hd) + q (1 × hd) + scratch ≈
 from __future__ import annotations
 
 import functools
+from typing import Optional
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 __all__ = ["flash_decode"]
 
@@ -34,6 +37,7 @@ NEG_INF = -1e30
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             *, bt: int, hd: int):
+    length = len_ref[pl.program_id(0)]
     t_idx = pl.program_id(1)
     nt = pl.num_programs(1)
 
@@ -48,7 +52,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     vb = v_ref[0].astype(jnp.float32)
     s = jnp.sum(q * kb, axis=-1)[None, :]                     # (1, bt)
     pos = t_idx * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
-    valid = pos < len_ref[0, 0]
+    valid = pos < length
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]                                       # (1, 1)
@@ -74,8 +78,9 @@ def flash_decode(
     length: jax.Array,   # (B,) int32: valid cache prefix per request
     *,
     bt: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     b, h, _, hd = q.shape
     t = k.shape[2]
     bt = min(bt, t)
@@ -85,13 +90,13 @@ def flash_decode(
     qf = q.reshape(bh, 1, hd)
     kf = k.reshape(bh, t, hd)
     vf = v.reshape(bh, t, hd)
-    lens = jnp.repeat(length.astype(jnp.int32), h).reshape(bh, 1)
+    lens = jnp.repeat(length.astype(jnp.int32), h)  # (bh,)
 
     out = pl.pallas_call(
         functools.partial(_kernel, bt=bt, hd=hd),
         grid=(bh, t // bt),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),         # length
+            pl.BlockSpec(memory_space=pltpu.SMEM),             # lengths
             pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0)),  # q
             pl.BlockSpec((1, bt, hd), lambda i, j: (i, j, 0)),  # k block
             pl.BlockSpec((1, bt, hd), lambda i, j: (i, j, 0)),  # v block

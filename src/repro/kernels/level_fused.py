@@ -72,35 +72,121 @@ def fused_rows(n: int, key_bytes: int, k: int) -> int:
     return launch_spec("level_fused", key_bytes, k, n=n).rows
 
 
-def _rank_and_hist(bucket, nb: int, rows: int):
-    """Tile-local (rank-in-bucket-run, histogram) via one one-hot pass.
+def padded_buckets(nb: int) -> int:
+    """Sublanes of the in-kernel one-hot: nb rounded up to the bf16 tile
+    (16 rows), so the one-hot feeds the MXU unpadded."""
+    return -(-nb // 16) * 16
 
-    One inclusive cumsum serves both outputs: its contraction with the
-    one-hot is rank+1 (so the exclusive-prefix subtraction folds into a
-    scalar -1), and its last row IS the tile histogram (no second
-    reduction over the (tile, nb) sheet).  Rows whose id falls outside
-    [0, nb) — the self-padding trash id — have an all-zero one-hot and
-    get rank -1; their destinations are trimmed by every caller.
+
+def rank_rows(bucket_ref, rank_ref, rows: int, nbp: int, run):
+    """Stable in-bucket ranks of a (rows, 128) tile of bucket ids, row by
+    row in flat order, written to ``rank_ref`` as ``run[b] + #(earlier
+    elements of the tile with id b)``.
+
+    ``run`` is a (nbp, 128) int32 per-bucket count with every lane equal
+    (the bucket offsets at the tile start).  Returns ``run`` plus the tile
+    histogram, same layout.  Each row is one MXU product: its (nbp, 128)
+    one-hot against ``[strictly-upper | ones]`` (128, 256) gives, per
+    bucket, the count at earlier lanes and the row total.  0/1 operands in
+    bf16 with f32 accumulation are exact (no sum exceeds 128).  Ids
+    outside [0, nbp) match no one-hot row: they count nowhere and their
+    rank is unspecified (callers trim them).
     """
-    flat = bucket.reshape(rows * LANES, 1)
-    ids = jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1)
-    onehot = (flat == ids).astype(jnp.int32)  # (tile, nb)
-    # dtype= pins the x64-mode accumulators to the int32 output refs
-    incl = jnp.cumsum(onehot, axis=0, dtype=jnp.int32)
-    rank = jnp.sum(incl * onehot, axis=1, dtype=jnp.int32) - 1  # (tile,)
-    hist = incl[-1, :]  # (nb,)
-    return rank.reshape(rows, LANES), hist[None, :]
+    ids = jax.lax.broadcasted_iota(jnp.int32, (nbp, LANES), 0)
+    tri = (
+        jax.lax.broadcasted_iota(jnp.int32, (LANES, 2 * LANES), 0)
+        < jax.lax.broadcasted_iota(jnp.int32, (LANES, 2 * LANES), 1)
+    ).astype(jnp.bfloat16)
+
+    def body(r, run):
+        hit = ids == bucket_ref[pl.ds(r, 1), :]  # (nbp, 128)
+        cnt = jnp.dot(
+            hit.astype(jnp.bfloat16), tri, preferred_element_type=jnp.float32
+        ).astype(jnp.int32)  # (nbp, 256): [earlier lanes | row total]
+        before = cnt[:, :LANES] + run
+        rank_ref[pl.ds(r, 1), :] = jnp.sum(
+            jnp.where(hit, before, 0), axis=0, keepdims=True, dtype=jnp.int32
+        )
+        return run + cnt[:, LANES:]
+
+    return jax.lax.fori_loop(0, rows, body, run)
 
 
-def _classify_tile(keys, spl, *, k: int, classifier: str, consumed: int):
-    """Local bucket ids in [0, 2k) for one (rows, LANES) tile."""
+def column_to_row(col, nb: int):
+    """(nbp, 128) lanes-equal int32 per-bucket counts -> (1, nb) row.
+
+    Exact and VPU-only: each 128-bucket chunk selects its diagonal against
+    an identity mask and reduces over sublanes."""
+    parts = []
+    for lo in range(0, col.shape[0], LANES):
+        c = col[lo:lo + LANES, :]
+        eye = jax.lax.broadcasted_iota(
+            jnp.int32, c.shape, 0
+        ) == jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
+        parts.append(
+            jnp.sum(jnp.where(eye, c, 0), axis=0, keepdims=True, dtype=jnp.int32)
+        )
+    row = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    return row[:, :nb]
+
+
+def tile_histogram(bucket_ref, rows: int, nb: int):
+    """(1, nb) int32 histogram of a (rows, 128) tile of ids in
+    [0, nb): per-lane one-hot counts accumulate row by row, then one lane
+    reduction per bucket."""
+    nbp = padded_buckets(nb)
+    ids = jax.lax.broadcasted_iota(jnp.int32, (nbp, LANES), 0)
+
+    def body(r, acc):
+        return acc + jnp.where(ids == bucket_ref[pl.ds(r, 1), :], 1, 0)
+
+    acc = jax.lax.fori_loop(0, rows, body, jnp.zeros((nbp, LANES), jnp.int32))
+    col = jnp.sum(acc, axis=1, keepdims=True, dtype=jnp.int32)
+    return column_to_row(jnp.broadcast_to(col, (nbp, LANES)), nb)
+
+
+def _rank_and_hist(bucket_ref, rank_ref, hist_ref, *, nb: int, rows: int):
+    """Tile-local (rank-in-bucket-run, histogram) of the ids in
+    ``bucket_ref``: ranks to ``rank_ref``, the (1, 1, nb) histogram to
+    ``hist_ref``."""
+    nbp = padded_buckets(nb)
+    run = rank_rows(
+        bucket_ref, rank_ref, rows, nbp, jnp.zeros((nbp, LANES), jnp.int32)
+    )
+    hist_ref[0] = column_to_row(run, nb)
+
+
+def classify_tile(keys, spl_ref, *, k: int, classifier: str, consumed: int):
+    """Local bucket ids in [0, 2k) for one (rows, LANES) tile.
+
+    Tree mode compares the whole tile against one splitter per step;
+    ``spl_ref`` is a (1, k, 128) block whose row i holds upper i (the k-1
+    splitters, then the dtype sentinel) on every lane.  ``j`` counts only
+    the k-1 real splitters (a key above the sentinel, e.g. +inf, still
+    lands in bucket k-1); ``eq`` compares against all k uppers."""
     if classifier == "radix":
         return radix_bucket_ids(keys, k, consumed)
-    kf = keys[:, :, None]  # (rows, 128, 1)
-    sf = spl[0][None, None, :]  # (1, 1, k): k-1 splitters + sentinel upper
-    j = jnp.sum((kf > sf[..., : k - 1]).astype(jnp.int32), axis=-1, dtype=jnp.int32)
-    eq = jnp.any(kf == sf, axis=-1).astype(jnp.int32)
+
+    def body(i, carry):
+        j, eq = carry
+        s = spl_ref[0, pl.ds(i, 1), :]  # (1, 128)
+        j = j + jnp.where((keys > s) & (i < k - 1), 1, 0)
+        eq = jnp.maximum(eq, jnp.where(keys == s, 1, 0))
+        return j, eq
+
+    zero = jnp.zeros(keys.shape, jnp.int32)
+    j, eq = jax.lax.fori_loop(0, k, body, (zero, zero))
     return 2 * j + eq
+
+
+def splitter_block(splitters: jax.Array, k: int) -> jax.Array:
+    """(..., k-1) splitters -> (B, k, 128) kernel operand: the dtype
+    sentinel appended as the last bucket's upper, each upper broadcast
+    across the lanes."""
+    spl = splitters.reshape((-1, k - 1))
+    sent = jnp.full((spl.shape[0], 1), sentinel_for(spl.dtype), spl.dtype)
+    upper = jnp.concatenate([spl, sent], axis=1)
+    return jnp.broadcast_to(upper[:, :, None], upper.shape + (LANES,))
 
 
 def _fused_kernel(
@@ -109,13 +195,14 @@ def _fused_kernel(
 ):
     if classifier == "radix":
         keys_ref, bucket_ref, rank_ref, hist_ref = refs
-        spl = None
+        spl_ref = None
     else:
         keys_ref, spl_ref, bucket_ref, rank_ref, hist_ref = refs
-        spl = spl_ref[...]
     tile_id = pl.program_id(1) if tiles_per_row else pl.program_id(0)
     keys = keys_ref[...]  # (rows, 128)
-    bucket = _classify_tile(keys, spl, k=k, classifier=classifier, consumed=consumed)
+    bucket = classify_tile(
+        keys, spl_ref, k=k, classifier=classifier, consumed=consumed
+    )
     # in-kernel pad routing: positions >= n_real (within the row, for the
     # batched grid) belong to the dedicated pad bucket 2k
     tile = rows * LANES
@@ -124,13 +211,12 @@ def _fused_kernel(
         + jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0) * LANES
         + jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
     )
-    bucket = jnp.where(pos >= n_real, 2 * k, bucket)
-    bucket_ref[...] = bucket
-    rank_ref[...], hist_ref[...] = _rank_and_hist(bucket, nb, rows)
+    bucket_ref[...] = jnp.where(pos >= n_real, 2 * k, bucket)
+    _rank_and_hist(bucket_ref, rank_ref, hist_ref, nb=nb, rows=rows)
 
 
 def _ids_kernel(bid_ref, rank_ref, hist_ref, *, nb: int, rows: int):
-    rank_ref[...], hist_ref[...] = _rank_and_hist(bid_ref[...], nb, rows)
+    _rank_and_hist(bid_ref, rank_ref, hist_ref, nb=nb, rows=rows)
 
 
 def _close_placement(bucket, rank, hist, nb: int, tile: int):
@@ -208,11 +294,8 @@ def level_fused(
     in_specs = [pl.BlockSpec((rows, LANES), lambda i: (i, 0))]
     operands = [keys2]
     if classifier != "radix":
-        upper = jnp.concatenate(
-            [splitters, jnp.full((1,), sentinel_for(splitters.dtype), splitters.dtype)]
-        )
-        in_specs.append(pl.BlockSpec((1, k), lambda i: (0, 0)))
-        operands.append(upper.reshape(1, k))
+        in_specs.append(pl.BlockSpec((1, k, LANES), lambda i: (0, 0, 0)))
+        operands.append(splitter_block(splitters, k))
 
     bucket, rank, hist = pl.pallas_call(
         kern,
@@ -221,16 +304,18 @@ def level_fused(
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, nb), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, nb), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((num_tiles * rows, LANES), jnp.int32),
             jax.ShapeDtypeStruct((num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, nb), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
-    return _close_placement(bucket.reshape(n), rank.reshape(n), hist, nb, tile)
+    return _close_placement(
+        bucket.reshape(n), rank.reshape(n), hist.reshape(num_tiles, nb), nb, tile
+    )
 
 
 @functools.partial(
@@ -275,15 +360,8 @@ def level_fused_batched(
     in_specs = [pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0))]
     operands = [keys2]
     if classifier != "radix":
-        upper = jnp.concatenate(
-            [
-                splitters,
-                jnp.full((B, 1), sentinel_for(splitters.dtype), splitters.dtype),
-            ],
-            axis=1,
-        )
-        in_specs.append(pl.BlockSpec((1, k), lambda b, i: (b, 0)))
-        operands.append(upper)
+        in_specs.append(pl.BlockSpec((1, k, LANES), lambda b, i: (b, 0, 0)))
+        operands.append(splitter_block(splitters, k))
 
     bucket, rank, hist = pl.pallas_call(
         kern,
@@ -292,12 +370,12 @@ def level_fused_batched(
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
             pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-            pl.BlockSpec((1, nb), lambda b, i: (b * num_tiles + i, 0)),
+            pl.BlockSpec((1, 1, nb), lambda b, i: (b * num_tiles + i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * num_tiles * rows, LANES), jnp.int32),
             jax.ShapeDtypeStruct((B * num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((B * num_tiles, nb), jnp.int32),
+            jax.ShapeDtypeStruct((B * num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
@@ -346,16 +424,17 @@ def rank_hist(
         in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, nb), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, nb), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, nb), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
     )(bid2)
     dest, offsets = _close_placement(
-        bucket.reshape(n_pad), rank.reshape(n_pad), hist, nb, tile
+        bucket.reshape(n_pad), rank.reshape(n_pad), hist.reshape(num_tiles, nb),
+        nb, tile,
     )
     return dest[:n], offsets
 
@@ -397,11 +476,11 @@ def rank_hist_batched(
         in_specs=[pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0))],
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-            pl.BlockSpec((1, nb), lambda b, i: (b * num_tiles + i, 0)),
+            pl.BlockSpec((1, 1, nb), lambda b, i: (b * num_tiles + i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((B * num_tiles, nb), jnp.int32),
+            jax.ShapeDtypeStruct((B * num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
     )(bid2)

@@ -13,32 +13,28 @@ The TPU formulation splits the work in two branch-free stages:
      one fori_loop of ceil(log2 nA)+1 dense gather steps, no kernel needed.
   2. **In-tile merge** (the Pallas kernel): tile t owns output range
      [d_t, d_{t+1}) which merge-path guarantees is exactly
-     A[ia:ia+la] ++ B[ja:ja+lb].  The two windows are merged by a
-     branchless **bitonic merger**: window A ascending ++ window B
-     *reversed* is a bitonic sequence of 2T (key, src) pairs, so
-     log2(2T) compare-exchange rounds — each a dense VPU select at
-     distance d = T..1, the same static-reshape idiom as
-     ``kernels.bitonic`` — sort it ascending.  Ranking is lexicographic
-     on (key, src) with every A source index (< nA) below every B source
-     index (>= nA), which realizes the stable tie rule *exactly* (ties to
-     A, order preserved within runs) with no tie-epsilon.  Lanes beyond
-     la/lb mask to (sentinel key, 2^30 src) and sink to the tail.  Versus
-     the previous dense (T, T) cross-rank compare + one-hot contraction,
-     the merger does O(T log T) work instead of O(T^2) — at T = 256
-     that is ~18 dense ops on 2T lanes instead of ~2 on T^2 cells, an
-     ~8x compute drop, and the win grows linearly in T.
+     A[ia:ia+la] ++ B[ja:ja+lb].  The wrapper gathers each tile's two
+     windows into one row of (key, src) pairs — window A ascending ++
+     window B *reversed*, a bitonic sequence of 2T pairs — and the kernel
+     sorts eight such rows per grid step with a branchless **bitonic
+     merger**: log2(2T) compare-exchange rounds, each two lane rotations
+     and a dense VPU select at distance d = T..1.  Ranking is
+     lexicographic on (key, src) with every A source index (< nA) below
+     every B source index (>= nA), which realizes the stable tie rule
+     *exactly* (ties to A, order preserved within runs) with no
+     tie-epsilon.  Lanes beyond la/lb mask to (sentinel key, 2^30 src)
+     and sink to the tail.  The merger does O(T log T) work per tile.
 
 The kernel emits a *permutation* (int32 source index into ``A ++ B``), not
 merged keys: the wrapper layers (``repro.stream.merge``) gather keys and
 arbitrary payload pytrees through it, which is also what makes the merge
 trivially stable for (key, payload) rows.
 
-Per-tile scalars (window starts/lengths) ride in as a (num_tiles, 4) array
-consumed through a per-tile BlockSpec — the same idiom as flash_decode's
-``length`` operand — and the windows themselves are dynamic ``pl.ds``
-slices of the full (VMEM-resident) runs.  The default T comes from the
-unified ``launch.roofline.KernelLaunchSpec`` (kind ``"merge"``); the
-stream plan cache sweeps the spec's candidate tiles.
+The windows are gathered in XLA (from the per-tile starts and lengths),
+so every kernel block is a dense (8, 2T) tile with T a multiple of 128.
+The default T comes from the unified ``launch.roofline.KernelLaunchSpec``
+(kind ``"merge"``); the stream plan cache sweeps the spec's candidate
+tiles.
 """
 from __future__ import annotations
 
@@ -50,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 
@@ -109,48 +106,35 @@ def merge_path_partition(a: jax.Array, b: jax.Array, d: jax.Array) -> jax.Array:
 # sentinel (>= all keys) and the src outranks any real source index
 _PAD_SRC = 1 << 30
 
-
-def _merge_exchange(k, s, d: int, W: int):
-    """One always-ascending merger round at distance ``d``: partner =
-    idx ^ d via the static (W/2d, 2, d) reshape; swap on lexicographic
-    (key, src) greater-than."""
-    shape = (W // (2 * d), 2, d)
-    k3, s3 = k.reshape(shape), s.reshape(shape)
-    (k_lo, s_lo), (k_hi, s_hi) = (k3[:, 0], s3[:, 0]), (k3[:, 1], s3[:, 1])
-    swap = (k_lo > k_hi) | ((k_lo == k_hi) & (s_lo > s_hi))
-    k = jnp.stack(
-        [jnp.where(swap, k_hi, k_lo), jnp.where(swap, k_lo, k_hi)], axis=1
-    ).reshape(W)
-    s = jnp.stack(
-        [jnp.where(swap, s_hi, s_lo), jnp.where(swap, s_lo, s_hi)], axis=1
-    ).reshape(W)
-    return k, s
+# merge tiles per grid step: one (8, 2T) block, the 32-bit sublane tile
+_TILES_PER_STEP = 8
 
 
-def _merge_kernel(meta_ref, a_ref, b_ref, perm_ref, *, T: int, nA: int, sent):
-    ia = meta_ref[0, 0]  # A window start
-    ja = meta_ref[0, 1]  # B window start
-    la = meta_ref[0, 2]  # A elements owned by this tile
-    lb = meta_ref[0, 3]  # B elements owned by this tile
-    aw = a_ref[0, pl.ds(ia, T)]  # (T,) — only the first la lanes are real
-    bw = b_ref[0, pl.ds(ja, T)]
-    p = jax.lax.iota(jnp.int32, T)  # local window index
-    # (key, src) pairs; src orders A (< nA) wholly before B (>= nA), and by
-    # run position within each — lexicographic sort == the stable merge
-    ka = jnp.where(p < la, aw, sent)
-    sa = jnp.where(p < la, ia + p, _PAD_SRC)
-    kb = jnp.where(p < lb, bw, sent)
-    sb = jnp.where(p < lb, nA + ja + p, _PAD_SRC)
-    # A ascending ++ B reversed (descending) is bitonic in (key, src):
-    # within a run src ascends with key, and A-pads/B-pads sit at the
-    # sequence's two ends' tails where monotonicity is preserved
-    k = jnp.concatenate([ka, kb[::-1]])
-    s = jnp.concatenate([sa, sb[::-1]])
-    for dp in range(int(math.log2(2 * T)) - 1, -1, -1):
-        k, s = _merge_exchange(k, s, 1 << dp, 2 * T)
-    # first T sorted srcs are this tile's outputs (slots >= la+lb — final
+def _merge_exchange(k, s, lane, d: int, W: int):
+    """One always-ascending merger round at distance ``d`` on (rows, W)
+    blocks: partner = lane ^ d, fetched with two lane rotations; the
+    lower lane keeps the lexicographic (key, src) minimum.  The rotated
+    lane index picks which rotation holds the partner, so the round does
+    not depend on the rotation's sign convention."""
+    roll = lambda x, sh: pltpu.roll(x, sh, 1)
+    fwd = roll(lane, d) == (lane ^ d)
+    kp = jnp.where(fwd, roll(k, d), roll(k, W - d))
+    sp = jnp.where(fwd, roll(s, d), roll(s, W - d))
+    p_less = (kp < k) | ((kp == k) & (sp < s))
+    take = jnp.logical_xor(p_less, (lane & d) != 0)  # upper lane keeps the max
+    return jnp.where(take, kp, k), jnp.where(take, sp, s)
+
+
+def _merge_kernel(k_ref, s_ref, perm_ref, *, T: int):
+    k = k_ref[...]  # (rows, 2T): A window ++ reversed B window, per tile
+    s = s_ref[...]
+    W = 2 * T
+    lane = jax.lax.broadcasted_iota(jnp.int32, k.shape, 1)
+    for dp in range(int(math.log2(W)) - 1, -1, -1):
+        k, s = _merge_exchange(k, s, lane, 1 << dp, W)
+    # first T sorted srcs are each tile's outputs (slots >= la+lb — final
     # tile only — hold pad srcs and are sliced off by the wrapper)
-    perm_ref[0, :] = s[:T]
+    perm_ref[...] = s[:, :T]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -167,9 +151,10 @@ def merge_path_perm(
       a, b: 1-D sorted arrays of one dtype, totally ordered under ``<=``
         (raw NaNs are the callers' concern — ``repro.stream`` passes
         keyspace-encoded keys, exactly like the sort entry points).
-      tile: output elements per grid step (the merge-path T; power of two
-        — the in-tile bitonic merger runs log2(2T) rounds).  None derives
-        the ``KernelLaunchSpec`` default for this key width.
+      tile: output elements per merge tile (the merge-path T; a power of
+        two and a multiple of 128 for the chip — the in-tile bitonic
+        merger runs log2(2T) rounds).  None derives the
+        ``KernelLaunchSpec`` default for this key width.
       interpret: shared off-TPU policy via ``kernels.resolve_interpret``.
 
     Returns ``perm`` (nA+nB,) int32 with ``concat(a, b)[perm]`` equal to
@@ -192,27 +177,37 @@ def merge_path_perm(
     num_tiles = -(-n // tile)
     d = jnp.minimum(jnp.arange(num_tiles + 1, dtype=jnp.int32) * tile, n)
     part = merge_path_partition(a, b, d).astype(jnp.int32)
-    ia = part[:-1]
-    la = jnp.diff(part)
-    ja = d[:-1] - ia
-    lb = jnp.diff(d) - la
-    meta = jnp.stack([ia, ja, la, lb], axis=1)  # (num_tiles, 4) int32
-    # pad run tails so the T-wide dynamic window loads never read OOB (the
-    # pad values are masked by la/lb and never influence a rank)
-    La, Lb = nA + tile, nB + tile
-    ap = jnp.pad(a, (0, tile)).reshape(1, La)
-    bp = jnp.pad(b, (0, tile)).reshape(1, Lb)
+    ia = part[:-1, None]  # A window start
+    la = jnp.diff(part)[:, None]  # A elements owned by each tile
+    ja = d[:-1, None] - ia  # B window start
+    lb = jnp.diff(d)[:, None] - la
+    # gather each tile's (key, src) windows: A ascending ++ B reversed
+    # (descending) is bitonic in (key, src) — within a run src ascends
+    # with key, and the masked tails sit at the sequence's two ends
+    sent = _sentinel_np(a.dtype)
+    p = jnp.arange(tile, dtype=jnp.int32)[None, :]
+    q = tile - 1 - p  # reversed B window index
+    ap = jnp.pad(a, (0, tile))
+    bp = jnp.pad(b, (0, tile))
+    keys = jnp.concatenate([
+        jnp.where(p < la, jnp.take(ap, ia + p), sent),
+        jnp.where(q < lb, jnp.take(bp, ja + q), sent),
+    ], axis=1)
+    srcs = jnp.concatenate([
+        jnp.where(p < la, ia + p, _PAD_SRC),
+        jnp.where(q < lb, nA + ja + q, _PAD_SRC),
+    ], axis=1)
+    rows = -(-num_tiles // _TILES_PER_STEP) * _TILES_PER_STEP
+    keys = jnp.pad(keys, ((0, rows - num_tiles), (0, 0)), constant_values=sent)
+    srcs = jnp.pad(srcs, ((0, rows - num_tiles), (0, 0)), constant_values=_PAD_SRC)
 
+    block = lambda w: pl.BlockSpec((_TILES_PER_STEP, w), lambda t: (t, 0))
     perm = pl.pallas_call(
-        functools.partial(_merge_kernel, T=tile, nA=nA, sent=_sentinel_np(a.dtype)),
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda t: (t, 0)),  # per-tile scalars
-            pl.BlockSpec((1, La), lambda t: (0, 0)),  # run A (whole)
-            pl.BlockSpec((1, Lb), lambda t: (0, 0)),  # run B (whole)
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_tiles, tile), jnp.int32),
+        functools.partial(_merge_kernel, T=tile),
+        grid=(rows // _TILES_PER_STEP,),
+        in_specs=[block(2 * tile), block(2 * tile)],
+        out_specs=block(tile),
+        out_shape=jax.ShapeDtypeStruct((rows, tile), jnp.int32),
         interpret=interpret,
-    )(meta, ap, bp)
+    )(keys, srcs)
     return perm.reshape(-1)[:n]

@@ -29,11 +29,14 @@ per-bucket block multisets.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 __all__ = ["permute_blocks_inplace"]
 
@@ -152,7 +155,7 @@ def permute_blocks_inplace(
     *,
     k: int,
     block_elems: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """In-place block permutation.
 
@@ -166,6 +169,7 @@ def permute_blocks_inplace(
 
     Returns the permuted array (same buffer: input is aliased/donated).
     """
+    interpret = resolve_interpret(interpret)
     if block_elems % LANES:
         raise ValueError("block_elems must be a multiple of 128")
     brows = block_elems // LANES

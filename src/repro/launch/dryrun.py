@@ -86,13 +86,14 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     from repro.models.policy import compute_policy
 
     t0 = time.perf_counter()
-    with mesh:  # ambient mesh: resolves shard_hint P-constraints at trace
+    # ambient mesh: resolves shard_hint P-constraints at trace
+    with jax.set_mesh(mesh):
         with compute_policy(flash_block=flash_block, explicit_ep=explicit_ep):
             lowered = _lower(shape, cfg, mesh, specs, params_like, psh,
                              strat, tcfg)
     t_lower = time.perf_counter() - t0
     t0 = time.perf_counter()
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = lowered.compile()
     t_compile = time.perf_counter() - t0
     if hlo_out:

@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "dp_axes", "tp_axis"]
 
@@ -17,7 +18,9 @@ __all__ = ["make_production_mesh", "dp_axes", "tp_axis"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model code constrains shardings with bare
+    # PartitionSpecs (``models.layers.shard_hint``), which Explicit axes refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

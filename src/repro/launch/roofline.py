@@ -1,6 +1,7 @@
 """Roofline-term extraction from a compiled (unexecuted) XLA artifact.
 
-Three terms per (arch x shape x mesh) cell, TPU v5e constants:
+Three terms per (arch x shape x mesh) cell, from the target chip's peaks
+(``PEAKS``, keyed by ``device_kind``):
 
   compute    = HLO_FLOPs_global    / (chips * 197e12 FLOP/s bf16)
   memory     = HLO_bytes_global    / (chips * 819e9 B/s HBM)
@@ -22,19 +23,45 @@ import re
 from dataclasses import asdict, dataclass, field
 from typing import Dict, Optional
 
-__all__ = ["HW", "collective_bytes", "roofline_terms", "RooflineReport",
+__all__ = ["PEAKS", "peaks", "HW", "collective_bytes", "roofline_terms", "RooflineReport",
            "model_flops", "classify_tile_rows", "KernelLaunchSpec",
            "launch_spec", "spec_candidates"]
 
-# TPU v5e per chip
-HW = {
-    "peak_flops": 197e12,       # bf16
-    "hbm_bw": 819e9,            # B/s
-    "ici_bw": 50e9,             # B/s per link
-    "ici_links": 4,             # links/chip on a 2-D torus (16x16 pod)
-    "hbm_bytes": 16 * 2**30,    # capacity
-    "vmem_bytes": 16 * 2**20,   # VMEM per core — the Pallas tile budget
+# Per-chip peaks, keyed by ``jax.Device.device_kind``.  Source: Google
+# Cloud TPU documentation, "TPU v5e" system architecture (197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect over four
+# links); 16 MiB is Mosaic's default scoped-VMEM limit on that chip.
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,       # bf16
+        "hbm_bw": 819e9,            # B/s
+        "ici_bw": 50e9,             # B/s per link
+        "ici_links": 4,             # links/chip on a 2-D torus (16x16 pod)
+        "hbm_bytes": 16 * 2**30,    # capacity
+        "vmem_bytes": 16 * 2**20,   # scoped VMEM per core — the Pallas tile budget
+    },
 }
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table of one chip kind; a kind the table lacks is an
+    error, never a default.
+
+    >>> peaks("TPU v5 lite")["hbm_bw"]
+    819000000000.0
+    """
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak table for device kind {device_kind!r}; known: {sorted(PEAKS)}"
+        ) from None
+
+
+# The chip this repo's kernels and dry-run model target: the tile budget
+# below and ``roofline_terms`` read its peaks.
+TARGET_KIND = "TPU v5 lite"
+HW = peaks(TARGET_KIND)
 
 # unified kernel-launch model: lanes per VPU row, the VMEM fraction a
 # double-buffered kernel may claim for one grid step, and the largest row
@@ -318,7 +345,8 @@ def roofline_terms(
     """``flops_per_dev``/``bytes_per_dev`` are the RAW cost_analysis numbers
     (loop bodies counted once — see launch/hlo_cost.py).  We re-derive
     trip-count-corrected values from the HLO text and use THOSE for the
-    three terms; the raws are kept in the report for comparison."""
+    three terms; the raws are kept in the report for comparison.  The
+    peaks are ``HW``, the ``TARGET_KIND`` chip's."""
     from repro.launch.hlo_cost import analyze_hlo
 
     hc = analyze_hlo(hlo_text)

@@ -14,6 +14,7 @@ import argparse
 import sys
 
 import jax
+from jax.sharding import AxisType
 
 
 def main(argv=None) -> int:
@@ -45,7 +46,9 @@ def main(argv=None) -> int:
         adamw=AdamWConfig(lr=args.lr),
     )
     ndev = len(jax.devices())
-    mesh = jax.make_mesh((ndev, 1), ("data", "model"))
+    mesh = jax.make_mesh(
+        (ndev, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
     data = SyntheticLM(
         vocab_size=cfg.vocab_size, seq_len=args.seq, global_batch=args.batch,
         seed=args.seed, embed_dim=cfg.d_model if cfg.takes_embeds else 0,

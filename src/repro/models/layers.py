@@ -30,12 +30,7 @@ def shard_hint(x: jax.Array, *axes) -> jax.Array:
     Critical use: the logits constraint keeps the (B, S, vocab) tensor
     vocab-sharded instead of letting GSPMD replicate it (49 GB/dev -> fits).
     """
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh_axes = set(thread_resources.env.physical_mesh.axis_names)
-    except Exception:  # pragma: no cover - private API fallback
-        return x
+    mesh_axes = set(jax.sharding.get_abstract_mesh().axis_names)
     if not mesh_axes:
         return x
 

@@ -140,13 +140,9 @@ def _expert_mlp(experts: Params, xg: jax.Array) -> jax.Array:
 
 
 def _ambient_mesh():
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        return mesh if mesh.axis_names else None
-    except Exception:  # pragma: no cover
-        return None
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _moe_ep_shard_map(p, xf, gate_vals, eids, *, num_experts, top_k,
@@ -166,7 +162,6 @@ def _moe_ep_shard_map(p, xf, gate_vals, eids, *, num_experts, top_k,
     the WHOLE buffer per layer (the dominant collective term of both MoE
     archs' baseline roofline).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -219,12 +214,12 @@ def _moe_ep_shard_map(p, xf, gate_vals, eids, *, num_experts, top_k,
         return y, dropped, counts
 
     espec = jax.tree.map(lambda _: P(ep_axis, None, None), p["experts"])
-    f = shard_map(
+    f = jax.shard_map(
         column,
         mesh=mesh,
         in_specs=(P(dp, None), P(dp, None), P(dp, None), espec),
         out_specs=(P(dp, None), P(), P(ep_axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return f(xf, gate_vals, eids, p["experts"])
 

@@ -25,7 +25,7 @@ from typing import Any, NamedTuple, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.core.ips4o import SortConfig, ips4o_sort
+from repro.core.ips4o import SortConfig, ips4o_sort, replicated
 from repro.core.partition import partition_permutation
 from repro.ops import keyspace
 
@@ -125,6 +125,7 @@ def group_by(
                 jnp.zeros((num_groups,), jnp.int32), num_groups,
                 jnp.zeros((0,), jnp.int32),
             )
+        keys, values = replicated((keys, values))
         perm, offsets = _int_group_perm(keys, num_groups, method, tile)
         gk = jnp.take(keys, perm, axis=0)
         gv = (

@@ -36,6 +36,7 @@ from repro.core.ips4o import (
     pad_with_sentinel,
     partition_passes,
     plan_levels,
+    replicated,
     segment_ids,
     stable_full_sort,
 )
@@ -59,7 +60,7 @@ def smallest_encoded(
     per-shard candidate filter of the distributed rank-k query.
     """
     n = enc.shape[0]
-    arrays = {"k": enc, "v": jnp.arange(n, dtype=jnp.int32)}
+    arrays = {"k": replicated(enc), "v": jnp.arange(n, dtype=jnp.int32)}
     unit = max(cfg.base_case, cfg.tile)
     arrays = pad_with_sentinel(arrays, unit)
     n_pad = arrays["k"].shape[0]

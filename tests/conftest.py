@@ -9,17 +9,3 @@ import pytest
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _clear_jax_caches_between_modules():
-    """This jaxlib segfaults inside backend_compile once the in-process
-    compile history grows past a few hundred programs (the same fragility
-    that forces the x64 suites into subprocesses — see tests/test_classify.py).
-    Dropping the jit caches at module boundaries keeps the full tier-1 run
-    under that threshold; each module recompiles its own programs anyway, so
-    only cross-module cache hits are lost."""
-    yield
-    import jax
-
-    jax.clear_caches()

@@ -53,6 +53,7 @@ from repro.launch.roofline import (
     HW,
     _bytes_per_row,
     launch_spec,
+    peaks,
     spec_candidates,
 )
 
@@ -322,3 +323,12 @@ class TestKernelLaunchSpec:
     def test_merge_and_permute_kinds(self):
         assert launch_spec("merge", 4).tile == 1024
         assert spec_candidates("permute", 4)[0] <= 64
+
+
+def test_peak_table_keyed_by_device_kind():
+    """The roofline peaks belong to one chip kind; an unknown kind is an
+    error, never a silent default."""
+    assert peaks("TPU v5 lite") is HW
+    assert HW["hbm_bw"] == 819e9 and HW["peak_flops"] == 197e12
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")

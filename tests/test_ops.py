@@ -185,6 +185,49 @@ def test_topk_int_extremes():
     np.testing.assert_array_equal(np.asarray(v), np.sort(x)[::-1][:8])
 
 
+@pytest.mark.parametrize(
+    "entry", ["sort", "argsort", "bottomk", "topk", "group_by", "unique"]
+)
+def test_explicit_axis_sharded_input(entry):
+    # jax.make_mesh builds Explicit axes: an input placed on such a mesh
+    # carries its sharding in its type, and the engine's whole-array pads,
+    # scatters and gathers must still resolve (one device: no data moves)
+    import jax
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+    mesh = jax.make_mesh((1,), ("data",))
+    assert mesh.axis_types == (AxisType.Explicit,)
+    x = _rand(6_000, 4)
+    ids = (np.arange(6_000) * 7919 % 37).astype(np.int32)
+    xs, vs, gs = (
+        jax.device_put(jnp.asarray(a), NamedSharding(mesh, P("data")))
+        for a in (x, np.arange(6_000, dtype=np.int32), ids)
+    )
+    if entry == "sort":
+        k, v = ops.sort(xs, vs, cfg=_small_cfg)
+        np.testing.assert_array_equal(np.asarray(k), np.sort(x))
+        np.testing.assert_array_equal(np.asarray(v), np.argsort(x, kind="stable"))
+    elif entry == "argsort":
+        o = ops.argsort(xs, cfg=_small_cfg)
+        np.testing.assert_array_equal(np.asarray(o), np.argsort(x, kind="stable"))
+    elif entry in ("bottomk", "topk"):
+        v, i = getattr(ops, entry)(xs, 7, cfg=_small_cfg)
+        want = np.sort(x)[:7] if entry == "bottomk" else np.sort(x)[::-1][:7]
+        np.testing.assert_array_equal(np.asarray(v), want)
+        np.testing.assert_array_equal(x[np.asarray(i)], want)
+    elif entry == "group_by":
+        g = ops.group_by(gs, vs, num_groups=37)
+        order = np.argsort(ids, kind="stable")
+        np.testing.assert_array_equal(np.asarray(g.keys), ids[order])
+        np.testing.assert_array_equal(np.asarray(g.values), order)
+    else:
+        vals, counts, num = ops.unique(gs, cfg=_small_cfg)
+        want, want_counts = np.unique(ids, return_counts=True)
+        assert int(num) == len(want)
+        np.testing.assert_array_equal(np.asarray(vals)[: len(want)], want)
+        np.testing.assert_array_equal(np.asarray(counts)[: len(want)], want_counts)
+
+
 # ---------------------------------------------------------------- segmented
 @pytest.mark.parametrize("n,nseg", [(3_000, 4), (40_000, 9), (2_000, 1)])
 def test_segmented_sort(n, nseg):

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import flash_attention_ref
@@ -83,7 +84,7 @@ def test_explicit_ep_matches_baseline():
     p = init_moe(jax.random.PRNGKey(0), d, num_experts=E, d_ff_expert=dff,
                  top_k=k, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, d), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     f = partial(moe_ffn, num_experts=E, top_k=k, capacity_factor=float(E))
 
     def run(ep):
@@ -94,7 +95,7 @@ def test_explicit_ep_matches_baseline():
             else:
                 y, aux = f(p, x)
             return y, aux
-        with mesh:
+        with jax.set_mesh(mesh):
             y, aux = jax.jit(g)(p, x)
             grads = jax.jit(jax.grad(lambda p: jnp.sum(g(p, x)[0] ** 2)))(p)
         return y, aux, grads

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs.registry import get_reduced
 from repro.models.transformer import init_model
@@ -13,7 +14,7 @@ from repro.serve.engine import Engine, ServeConfig
 @pytest.fixture(scope="module")
 def setup():
     cfg = get_reduced("yi-9b", num_layers=1)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     params = init_model(jax.random.PRNGKey(0), cfg)
     return cfg, mesh, params
 
@@ -36,13 +37,13 @@ def test_double_generate_matches_fresh_engines(setup):
     p_short = _prompts(cfg, 2, 4, seed=2)
 
     engine = Engine(cfg, scfg, mesh, params)
-    with mesh:
+    with jax.set_mesh(mesh):
         out1 = engine.generate(p_long, 6)
         out2 = engine.generate(p_short, 6)
 
     fresh1 = Engine(cfg, scfg, mesh, params)
     fresh2 = Engine(cfg, scfg, mesh, params)
-    with mesh:
+    with jax.set_mesh(mesh):
         ref1 = fresh1.generate(p_long, 6)
         ref2 = fresh2.generate(p_short, 6)
 
@@ -57,7 +58,7 @@ def test_sampled_generate_deterministic_per_seed(setup):
     scfg = ServeConfig(max_seq=32, batch_size=2, temperature=1.0)
     p = _prompts(cfg, 2, 8, seed=3)
     engine = Engine(cfg, scfg, mesh, params)
-    with mesh:
+    with jax.set_mesh(mesh):
         a = engine.generate(p, 8, seed=0)
         b = engine.generate(p, 8, seed=0)
         c = engine.generate(p, 8, seed=1)

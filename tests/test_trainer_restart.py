@@ -8,6 +8,7 @@ fast-forwards the stream, and the step is deterministic on CPU).
 import numpy as np
 import jax
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs.registry import get_reduced
 from repro.data.pipeline import SyntheticLM
@@ -20,7 +21,7 @@ def test_restart_bitwise_identical(tmp_path):
     cfg = get_reduced("yi-9b")
     tcfg = TrainConfig(microbatch=2, warmup_steps=2, total_steps=6,
                        adamw=AdamWConfig(lr=1e-3))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     data = lambda: iter(SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32,
                                     global_batch=4, seed=7))
 
