@@ -15,9 +15,7 @@ Matching and tracking rules:
   * tracked metrics are lower-is-better wall-clock fields:
     ``s_per_call``, ``*_us``, ``us``, ``*ns_per_elem``, ``t`` — except
     reference-implementation columns (``loop_us``, ``single_us``), whose
-    variance is a comparison moving, not a product path regressing, and
-    the ``phase_*`` attribution columns of the obs-trace bench (staged
-    subtractions, reference-only);
+    variance is a comparison moving, not a product path regressing;
   * rows present in only one file are reported but never fail the gate
     (CI runs ``--quick --only <subset>``; new benches land baseline-first);
   * intentional regressions go in the allowlist
@@ -45,10 +43,6 @@ _TRACKED_SUFFIX = ("_us", "ns_per_elem")
 # not a product regression — the engine column of the same row is what the
 # gate tracks
 _REFERENCE_METRICS = {"loop_us", "single_us", "lexsort_us"}
-# per-phase attribution columns (the obs-trace staged-subtraction table):
-# differences of isolated sub-step timings, informative but far too jittery
-# to gate — and not identity either (they vary run to run)
-_REFERENCE_PREFIXES = ("phase_",)
 # derived / environment fields: not metrics, not identity (the _bytes /
 # _flops families are the static observability columns of compiled_cost)
 _IGNORED_EXACT = {
@@ -60,15 +54,13 @@ _IGNORED_SUFFIX = (
 
 
 def is_tracked_metric(field: str) -> bool:
-    if field in _REFERENCE_METRICS or field.startswith(_REFERENCE_PREFIXES):
+    if field in _REFERENCE_METRICS:
         return False
     return field in _TRACKED_EXACT or field.endswith(_TRACKED_SUFFIX)
 
 
 def _is_identity(field: str) -> bool:
     if is_tracked_metric(field) or field in _IGNORED_EXACT:
-        return False
-    if field.startswith(_REFERENCE_PREFIXES):
         return False
     return not field.endswith(_IGNORED_SUFFIX)
 

@@ -15,8 +15,7 @@ from typing import Any, Callable, Dict, Iterable, List
 import jax
 import numpy as np
 
-__all__ = ["bench", "Row", "emit", "emit_json", "check_sorted", "compiled_cost",
-           "export_obs_trace"]
+__all__ = ["bench", "Row", "emit", "emit_json", "check_sorted", "compiled_cost"]
 
 Row = Dict[str, Any]
 
@@ -95,108 +94,6 @@ def emit(rows: Iterable[Row], header: List[str]) -> None:
     print(",".join(header))
     for r in rows:
         print(",".join(str(r.get(h, "")) for h in header))
-
-
-def export_obs_trace(prefix: str, n: int = 1 << 18) -> List[Row]:
-    """Run instrumented quick-shape sorts with ``repro.obs`` enabled and
-    export the trace: ``<prefix>.jsonl`` (spans + metrics), and
-    ``<prefix>.trace.json`` (Chrome trace-event JSON — load it at
-    https://ui.perfetto.dev).
-
-    Exercises every metric family the ISSUE names: plan-cache hit/miss +
-    compiled hit/miss (a fresh :class:`~repro.ops.plan.PlanCache` queried
-    twice), kernel launch-spec choices (one Pallas-engine sort at a
-    128-aligned size), the in-jit functional stats (bucket imbalance,
-    base-case counts), and a staged-subtraction per-phase attribution row
-    (``phase_*_us`` columns — untracked reference metrics in the perf
-    gate: each is a difference of isolated timings, honest about overlap
-    but too jittery to gate).
-    """
-    import os
-    import tempfile
-    from functools import partial
-
-    import jax.numpy as jnp
-
-    from repro import obs, ops
-    from repro.core import sampling
-    from repro.core.ips4o import SortConfig, plan_levels
-    from repro.ops import keyspace
-    from repro.ops.plan import PlanCache
-
-    from benchmarks.sort_classifier import _classify_only, _partition_only
-
-    was = obs.enabled()
-    obs.enabled(True)
-    obs.reset()
-    jax.clear_caches()  # jits traced while disabled carry no obs hooks
-    try:
-        x = jnp.asarray(
-            np.random.default_rng(0).standard_normal(n), jnp.float32
-        )
-        # plan-cache traffic: miss + compiled-miss at the full shape, then
-        # an autotuned small shape (sweep + persisted plan) looked up twice
-        # -> hit, plus a compiled-hit on the re-request
-        cache = PlanCache(path=os.path.join(tempfile.mkdtemp(), "plans.json"))
-        f = cache.get_sorter(n, jnp.float32)
-        with obs.trace("ops.sort:jit", n=n):
-            obs.block(f(x))
-        cache.get_sorter(n, jnp.float32)
-        m = 1 << 12
-        cache.get_sorter(m, jnp.float32, tune=True)
-        cache.config_for("sort", m, jnp.float32)
-
-        # one Pallas-engine level pass at a 128-aligned size: the fused
-        # kernel resolves its tile through launch_spec -> launch.spec counts
-        small = SortConfig(base_case=1024, tile=512, max_sample=1024,
-                           engine="pallas")
-        g = jax.jit(partial(ops.sort, cfg=small))
-        with obs.trace("ops.sort:pallas", n=1 << 13):
-            obs.block(g(x[: 1 << 13]))
-
-        # staged-subtraction phase attribution at the full shape
-        cfg = SortConfig(engine="xla")
-        k = plan_levels(n, cfg)[0]
-        rng = jax.random.PRNGKey(0)
-        f_enc = jax.jit(keyspace.encode)
-        enc = jax.block_until_ready(f_enc(x))
-
-        def _sample_only(e, r):
-            m1 = min(max(sampling.oversampling_factor(n) * k, k),
-                     cfg.max_sample, n)
-            pos = jax.random.randint(r, (m1,), 0, n)
-            return sampling.select_splitters(
-                jnp.sort(jnp.take(e, pos, axis=0)), k)
-
-        f_sample = jax.jit(_sample_only)
-        f_clf = jax.jit(partial(_classify_only, k=k, cfg=cfg, clf="tree"))
-        f_part = jax.jit(partial(_partition_only, cfg=cfg))
-        f_full = jax.jit(partial(ops.sort, cfg=cfg))
-
-        tenc = obs.timed_min("phase:encode", lambda: f_enc(x), n=n)
-        ts = obs.timed_min("phase:sample", lambda: f_sample(enc, rng), n=n)
-        tc = obs.timed_min("phase:classify+sample",
-                           lambda: f_clf(enc, rng), n=n)
-        tp = obs.timed_min("phase:levels", lambda: f_part(enc), n=n)
-        tf = obs.timed_min("phase:total", lambda: f_full(x), n=n)
-        row: Row = {
-            "bench": "obs_trace", "n": n, "dtype": "float32",
-            "phase_encode_us": round(tenc * 1e6, 1),
-            "phase_sample_us": round(ts * 1e6, 1),
-            "phase_classify_us": round(max(tc - ts, 0.0) * 1e6, 1),
-            "phase_partition_us": round(max(tp - tc, 0.0) * 1e6, 1),
-            "phase_base_case_us": round(max(tf - tp - 2 * tenc, 0.0) * 1e6, 1),
-            "phase_total_us": round(tf * 1e6, 1),
-        }
-        jax.effects_barrier()  # flush pending in-jit metric callbacks
-        obs.export_jsonl(prefix + ".jsonl")
-        obs.export_chrome_trace(prefix + ".trace.json")
-        print(obs.summary())
-        return [row]
-    finally:
-        obs.enabled(was)
-        obs.reset()
-        jax.clear_caches()
 
 
 def emit_json(all_rows: Dict[str, List[Row]], path: str) -> None:

@@ -10,11 +10,6 @@ readable without parsing JSON.
 Matching and "tracked metric" rules are imported from
 ``benchmarks.check_regression`` — the dashboard and the gate can never
 disagree about which rows correspond or which columns matter.
-
-With ``--trace BENCH_trace.jsonl`` (the JSONL half of ``benchmarks.run
---trace``), the report also renders a per-phase attribution table from
-the recorded spans — where one instrumented sort spent its time, by span
-name, with ``phase:*`` staged timings listed first.
 """
 from __future__ import annotations
 
@@ -135,57 +130,11 @@ def render(
     return "\n".join(lines) + "\n"
 
 
-def attribution(trace_path: str) -> str:
-    """Markdown per-phase attribution table from an obs JSONL trace.
-
-    Aggregates the trace's span lines by name (count / min / total);
-    ``phase:*`` spans — the staged-subtraction timers — sort first, the
-    remaining structural spans after, both by descending total time.
-    Returns "" when the file is missing or holds no spans.
-    """
-    agg: Dict[str, List[float]] = {}
-    try:
-        with open(trace_path) as fh:
-            for line in fh:
-                line = line.strip()
-                if not line:
-                    continue
-                rec = json.loads(line)
-                if rec.get("type") != "span":
-                    continue
-                name, dur = rec["name"], float(rec.get("dur_us", 0.0))
-                cur = agg.setdefault(name, [0, 0.0, float("inf")])
-                cur[0] += 1
-                cur[1] += dur
-                cur[2] = min(cur[2], dur)
-    except FileNotFoundError:
-        print(f"no obs trace at {trace_path}; skipping attribution table")
-        return ""
-    if not agg:
-        return ""
-    order = sorted(
-        agg.items(),
-        key=lambda kv: (not kv[0].startswith("phase:"), -kv[1][1]),
-    )
-    lines = [
-        "## Per-phase attribution (obs trace)", "",
-        f"from `{trace_path}` — `phase:*` rows are min-of-k staged timers, "
-        "the rest are structural spans (trace-time inside jit)", "",
-        "| span | count | min_us | total_us |",
-        "|---|---|---|---|",
-    ]
-    for name, (cnt, total, mn) in order:
-        lines.append(f"| {name} | {cnt} | {mn:.1f} | {total:.1f} |")
-    return "\n".join(lines) + "\n"
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--baseline", default="BENCH_sort.json")
     ap.add_argument("--fresh", default=None,
                     help="optional fresh-run json to diff against the baseline")
-    ap.add_argument("--trace", default=None,
-                    help="optional obs JSONL trace for the attribution table")
     ap.add_argument("--out", default="BENCH_report.md")
     args = ap.parse_args(argv)
 
@@ -199,8 +148,6 @@ def main(argv=None) -> int:
         except FileNotFoundError:
             print(f"no fresh run at {args.fresh}; rendering baseline only")
     md = render(baseline, fresh)
-    if args.trace:
-        md += "\n" + attribution(args.trace)
     with open(args.out, "w") as fh:
         fh.write(md)
     print(f"wrote {args.out}")

@@ -48,10 +48,6 @@ def main(argv=None) -> int:
                     help="comma-separated subset of benchmark modules")
     ap.add_argument("--json", default="BENCH_sort.json",
                     help="machine-readable output path ('' disables)")
-    ap.add_argument("--trace", default=None, metavar="PREFIX",
-                    help="export a repro.obs trace of one instrumented "
-                         "quick-shape sort: PREFIX.jsonl + PREFIX.trace.json "
-                         "(Perfetto), plus an obs_trace phase-attribution row")
     ap.add_argument("--list", action="store_true",
                     help="print the registered benchmark suites and exit")
     args = ap.parse_args(argv)
@@ -106,20 +102,6 @@ def main(argv=None) -> int:
         if rows:
             emit(rows, list(rows[0].keys()))
         print(f"-- {name} done in {time.perf_counter() - t0:.1f}s", flush=True)
-
-    if args.trace:
-        from benchmarks.common import export_obs_trace
-
-        print("\n== obs_trace ==", flush=True)
-        try:
-            rows = export_obs_trace(args.trace)
-            all_rows["obs_trace"] = rows
-            emit(rows, list(rows[0].keys()))
-        except Exception as e:  # pragma: no cover
-            import traceback
-            traceback.print_exc()
-            print(f"FAILED obs_trace: {type(e).__name__}: {e}")
-            failures += 1
 
     if args.json and all_rows:
         emit_json(all_rows, args.json)

@@ -47,6 +47,8 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 __all__ = [
     "tile_histogram",
     "stable_partition",
@@ -158,15 +160,17 @@ def stable_partition(
         dest, offsets = rank_hist(
             bucket.astype(jnp.int32), nb=nb, interpret=interpret
         )
-        out = jax.tree.map(
-            lambda a: jnp.zeros_like(a).at[dest].set(a, mode="promise_in_bounds"),
-            arrays,
-        )
+        with obs.layer("move"):
+            out = jax.tree.map(
+                lambda a: jnp.zeros_like(a).at[dest].set(a, mode="promise_in_bounds"),
+                arrays,
+            )
         return out, offsets
     if engine != "xla":
         raise ValueError(f"unknown partition engine {engine!r}; expected {ENGINES}")
     perm, offsets = partition_permutation(bucket, nb, tile)
-    out = jax.tree.map(lambda a: jnp.take(a, perm, axis=0), arrays)
+    with obs.layer("move"):
+        out = jax.tree.map(lambda a: jnp.take(a, perm, axis=0), arrays)
     return out, offsets
 
 

@@ -312,6 +312,7 @@ def level_fused(
             jax.ShapeDtypeStruct((num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
+        name="level_fused",
     )(*operands)
     return _close_placement(
         bucket.reshape(n), rank.reshape(n), hist.reshape(num_tiles, nb), nb, tile
@@ -378,6 +379,7 @@ def level_fused_batched(
             jax.ShapeDtypeStruct((B * num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
+        name="level_fused_batched",
     )(*operands)
     close = jax.vmap(functools.partial(_close_placement, nb=nb, tile=tile))
     return close(
@@ -431,6 +433,7 @@ def rank_hist(
             jax.ShapeDtypeStruct((num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
+        name="rank_hist",
     )(bid2)
     dest, offsets = _close_placement(
         bucket.reshape(n_pad), rank.reshape(n_pad), hist.reshape(num_tiles, nb),
@@ -483,6 +486,7 @@ def rank_hist_batched(
             jax.ShapeDtypeStruct((B * num_tiles, 1, nb), jnp.int32),
         ],
         interpret=interpret,
+        name="rank_hist_batched",
     )(bid2)
     close = jax.vmap(functools.partial(_close_placement, nb=nb, tile=tile))
     dest, offsets = close(

@@ -1,10 +1,11 @@
 """repro.obs — structured tracing, metrics, and profiling hooks.
 
-Low-overhead, **off-by-default** observability for the whole sort
-engine (DESIGN.md §12).  Enable with ``REPRO_OBS=1`` in the environment
-or ``obs.enabled(True)`` at runtime; while disabled every hook is a
-no-op that adds **zero traced ops and no host syncs** (verified by the
-jaxpr-identity test in ``tests/test_obs.py``).
+Low-overhead observability for the whole sort engine (DESIGN.md §12).
+Layer scopes are **always on**; recording is **off by default**.  Enable
+recording with ``REPRO_OBS=1`` in the environment or ``obs.enabled(True)``
+at runtime; while disabled every hook is a no-op that adds **zero traced
+ops and no host syncs** (verified by the jaxpr-identity test in
+``tests/test_obs.py``).
 
 Quickstart::
 
@@ -22,7 +23,11 @@ Three layers:
   host-side timing (callers hold ``block_until_ready`` discipline; see
   ``obs.block``/``obs.timed_min``) plus ``jax.profiler.TraceAnnotation``
   and ``jax.named_scope`` pass-through, so spans also land in XLA
-  profiles.
+  profiles.  ``obs.layer(name, **attrs)`` names one of the 1-D sort's
+  layers (``obs.LAYERS``): its ``jax.named_scope`` is entered whether or
+  not obs is enabled — metadata in the compiled program, no op, no host
+  sync — so a device profile can attribute every op to its layer; the
+  host span is recorded only while obs is enabled.
 * **Metrics** — counters/gauges/histograms, host-side (``count`` /
   ``gauge`` / ``observe``) and in-jit (``jit_count`` / ``jit_observe`` /
   ``jit_event``, staged as unordered ``jax.debug.callback`` only when
@@ -31,7 +36,8 @@ Three layers:
   ``export_chrome_trace`` (Perfetto-viewable Chrome trace-event file),
   ``summary()`` (human table).
 
-Instrumented call sites: ``core/ips4o.py`` (per-level spans,
+Instrumented call sites: ``ops/sort.py`` and ``core/ips4o.py`` (layer
+scopes on the 1-D sort, per-level spans on the batched path,
 bucket-imbalance / base-case / fallback stats), ``ops/plan.py``
 (plan-cache hit/miss/autotune, classifier races), ``classify/router.py``
 (routing decisions), ``dist/exchange.py`` (re-split rounds, collective
@@ -58,16 +64,19 @@ from repro.obs.metrics import (
     observe,
 )
 from repro.obs.tracer import (
+    LAYERS,
     Recorder,
     block,
     enabled,
     events,
+    layer,
     recorder,
     reset,
     trace,
 )
 
 __all__ = [
+    "LAYERS",
     "Recorder",
     "block",
     "count",
@@ -81,6 +90,7 @@ __all__ = [
     "jit_count",
     "jit_event",
     "jit_observe",
+    "layer",
     "metrics_snapshot",
     "observe",
     "recorder",

@@ -2,8 +2,7 @@
 
 * :func:`export_jsonl` — one JSON object per line, ``type`` in
   ``{span, event, counter, gauge, histogram}``.  The machine-readable
-  archive; ``benchmarks/report.py --trace`` builds its per-phase
-  attribution table from the span lines.
+  archive.
 * :func:`export_chrome_trace` — the Chrome trace-event format
   (``{"traceEvents": [...]}``, complete ``ph:"X"`` events in µs).  Open
   in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.

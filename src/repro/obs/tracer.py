@@ -21,6 +21,32 @@ Disabled (the default — enable with ``REPRO_OBS=1`` or
 ``obs.enabled(True)``), ``trace`` returns a shared allocation-free null
 span: no lock, no clock read, no jax import side effects, zero added
 traced ops.
+
+``layer(name, **attrs)`` splits the span's two jobs.  Its
+``jax.named_scope`` is **always on**: the scope is metadata fixed at trace
+time (a path component of every op's ``op_name`` in the compiled
+program), so it adds no op, no effect and no host sync, and a device
+profile names each op's layer whether or not obs is enabled.  Recording
+stays opt-in: only while obs is enabled does ``layer`` also record the
+host span and enter the ``TraceAnnotation``, exactly as ``trace`` does.
+Layer names come from one fixed vocabulary, :data:`LAYERS`:
+
+* ``sort`` — the 1-D sort's entry (``ops.sort``): keyspace encode, pad,
+  splitter RNG, decode and the final slice;
+* ``sort.level1`` — level 1 (``level_pass``): sample, classify (the fused
+  Pallas kernel and its prefix epilogue on that engine), partition;
+* ``sort.segment_ids`` — the bucket id of every position, searched in a
+  level's offsets (``segment_ids``), before level 2 and the base case;
+* ``sort.level2`` — level 2 (``segmented_level_pass``): sample, classify,
+  partition;
+* ``sort.base_case`` — the bucket check and the ``lax.cond`` between the
+  windowed base case (branch 0) and ``stable_full_sort`` (branch 1);
+* child scopes ``sample``, ``classify``, ``partition`` and ``move`` (the
+  payload scatters and gathers) inside those.
+
+An op's layer is the innermost ``sort.*`` scope in its ``op_name``, and a
+``move`` below it marks a payload move of that layer.  The benchmark's
+``scope.*`` readers (``bench/scopes.py``) attribute device time that way.
 """
 from __future__ import annotations
 
@@ -30,14 +56,30 @@ import time
 from typing import Any, Dict, List, Optional
 
 __all__ = [
+    "LAYERS",
     "Recorder",
     "block",
     "enabled",
     "events",
+    "layer",
     "recorder",
     "reset",
     "trace",
 ]
+
+#: the named scopes ``layer`` enters; the ``sort.*`` names are the layers,
+#: the last four their child steps (module docstring)
+LAYERS = (
+    "sort",
+    "sort.level1",
+    "sort.segment_ids",
+    "sort.level2",
+    "sort.base_case",
+    "sample",
+    "classify",
+    "partition",
+    "move",
+)
 
 _TRUTHY = ("1", "true", "True", "yes", "on")
 _STATE = {"enabled": os.environ.get("REPRO_OBS", "") in _TRUTHY}
@@ -256,6 +298,24 @@ def trace(name: str, *, recorder: Optional[Recorder] = None, **attrs: Any):
             return _NULL_SPAN
         rec = _RECORDER
     return _Span(rec, name, attrs)
+
+
+def layer(name: str, **attrs: Any):
+    """Layer scope: ``with obs.layer("sort.level1", k=128): ...``.
+
+    Always enters ``jax.named_scope(name)`` (trace-time metadata only:
+    no op, no effect, no host sync).  With obs enabled it is a full
+    :func:`trace` span instead, which enters the same scope and also
+    records the host span and the ``TraceAnnotation``.  ``name`` must be
+    one of :data:`LAYERS`.
+    """
+    if name not in LAYERS:
+        raise ValueError(f"unknown layer {name!r}; expected one of {LAYERS}")
+    if _STATE["enabled"]:
+        return _Span(_RECORDER, name, attrs)
+    import jax
+
+    return jax.named_scope(name)
 
 
 def block(x: Any) -> Any:

@@ -92,8 +92,8 @@ def sort(
     ([1, 2], [10, 20])
     """
     cfg = with_engine(cfg, engine, keys, classifier)
-    with obs.trace(
-        "ops.sort", n=keys.shape[0], dtype=str(keys.dtype), engine=cfg.engine
+    with obs.layer(
+        "sort", n=keys.shape[0], dtype=str(keys.dtype), engine=cfg.engine
     ):
         enc = keyspace.encode(keys)
         if values is None:

@@ -73,6 +73,30 @@ def test_disabled_adds_zero_traced_ops():
     assert not again.effects
 
 
+def test_disabled_layer_adds_no_equation_and_records_no_span():
+    """``obs.layer`` with obs off is a named scope and nothing more: the
+    jaxpr is the one without it, no span is recorded, and the scope
+    reaches the lowered program's op names."""
+    x = _keys(8)
+
+    def plain(a):
+        return a * 2.0 + 1.0
+
+    def scoped(a):
+        with obs.layer("sort.level1", k=4):
+            return plain(a)
+
+    assert str(jax.make_jaxpr(scoped)(x)) == str(jax.make_jaxpr(plain)(x))
+    assert obs.recorder().spans == []
+    assert "sort.level1/mul" in jax.jit(scoped).lower(x).as_text(debug_info=True)
+    with pytest.raises(ValueError, match="unknown layer"):
+        obs.layer("level_pass")
+    obs.enabled(True)
+    with obs.layer("sort.base_case", W=8):
+        pass
+    assert [s["name"] for s in obs.recorder().spans] == ["sort.base_case"]
+
+
 def test_disabled_null_span_is_shared_and_recorder_untouched():
     s1 = obs.trace("a")
     s2 = obs.trace("b", attr=1)
@@ -129,17 +153,19 @@ def test_enabled_eager_sort_spans_nest():
     np.testing.assert_array_equal(np.asarray(out), np.sort(np.asarray(x)))
     spans = obs.recorder().spans
     names = {s["name"] for s in spans}
-    assert {"ops.sort", "ips4o_sort", "level_pass", "sample", "classify",
-            "partition", "base_case"} <= names
+    assert {"sort", "ips4o_sort", "sort.level1", "sample", "classify",
+            "partition", "move", "sort.segment_ids", "sort.base_case"} <= names
     by_id = {s["id"]: s for s in spans}
-    root = next(s for s in spans if s["name"] == "ops.sort")
+    root = next(s for s in spans if s["name"] == "sort")
     assert root["parent"] is None and root["depth"] == 0
-    for child, parent in [("ips4o_sort", "ops.sort"),
-                          ("level_pass", "ips4o_sort"),
-                          ("sample", "level_pass"),
-                          ("classify", "level_pass"),
-                          ("partition", "level_pass"),
-                          ("base_case", "ips4o_sort")]:
+    for child, parent in [("ips4o_sort", "sort"),
+                          ("sort.level1", "ips4o_sort"),
+                          ("sample", "sort.level1"),
+                          ("classify", "sort.level1"),
+                          ("partition", "sort.level1"),
+                          ("move", "partition"),
+                          ("sort.segment_ids", "ips4o_sort"),
+                          ("sort.base_case", "ips4o_sort")]:
         s = next(s for s in spans if s["name"] == child)
         assert by_id[s["parent"]]["name"] == parent, (child, parent)
         assert s["dur_ns"] >= 0
@@ -263,9 +289,82 @@ def test_exporters_and_summary(tmp_path):
         if e["ph"] == "X":
             assert e["dur"] >= 0 and isinstance(e["ts"], float)
     # span names survive into the chrome trace
-    assert {"ops.sort", "level_pass"} <= {
+    assert {"sort", "sort.level1"} <= {
         e["name"] for e in evs if e["ph"] == "X"
     }
 
     s = obs.summary()
-    assert "ops.sort" in s and "spans" in s
+    assert "sort.level1" in s and "spans" in s
+
+
+# -- layer scopes in the compiled program ------------------------------------
+
+# two levels (kmax 16 at base_case 256 and n 8,192), fallback kept: both
+# branches of the base case's cond are compiled
+_LAYER_CFG = SortConfig(kmax=16, base_case=256)
+_LAYER_N = 8192
+
+
+def _bench_module(name):
+    import importlib
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    return importlib.import_module(name)
+
+
+def _layer_program():
+    from repro.core.ips4o import plan_levels
+
+    assert len(plan_levels(_LAYER_N, _LAYER_CFG)) == 2 and _LAYER_CFG.fallback
+    rng = np.random.default_rng(0)
+    k = jnp.asarray(rng.integers(0, 1000, _LAYER_N), jnp.int32)
+    v = (jnp.arange(_LAYER_N, dtype=jnp.int32), (jnp.zeros(_LAYER_N, jnp.uint32),))
+    return jax.jit(lambda k, v: ops.sort(k, v, engine="xla", cfg=_LAYER_CFG)).lower(k, v)
+
+
+def test_every_sort_op_carries_a_layer_scope():
+    """Every op the sort's trace gives an ``op_name`` names one of
+    ``obs.LAYERS``, every layer shows up, and the benchmark's scope
+    reader gives each op a part of the sort.  Ops XLA makes itself carry
+    no ``jit(...)`` root and are left out."""
+    devtrace, scopes = _bench_module("devtrace"), _bench_module("scopes")
+    names = devtrace.op_names(_layer_program().compile().as_text())
+    traced = [n for n in names.values() if n.startswith("jit(")]
+    assert traced
+    seen = set()
+    for name in traced:
+        path = name.split(";")[0].split("/")[:-1]
+        assert set(path) & set(obs.LAYERS), name
+        seen |= set(path)
+        assert scopes.part(name) is not None, name
+    assert {"sort.level1", "sort.segment_ids", "sort.level2", "sort.base_case"} <= seen
+    parts = {scopes.part(n) for n in traced}
+    assert {"entry", "level1", "level1_move", "segment_ids", "level2", "level2_move",
+            "base_case", "fallback", "fallback_move"} <= parts
+
+
+def _strip_debug(hlo: str) -> str:
+    """A compiled program's text without its debug information: op
+    metadata and the source-location tables."""
+    import re
+
+    hlo = re.sub(r", metadata=\{[^}]*\}", "", hlo)
+    return "\n".join(ln for ln in hlo.splitlines()
+                     if not re.match(r'^\d+ (\{file_name_id|")', ln))
+
+
+def test_layer_scopes_change_only_metadata(monkeypatch):
+    """The compiled program with the layer scopes equals the one without
+    them once the metadata is stripped: the scopes cost nothing at run
+    time."""
+    import contextlib
+
+    with_scopes = _layer_program().compile().as_text()
+    monkeypatch.setattr(obs, "layer", lambda name, **attrs: contextlib.nullcontext())
+    without = _layer_program().compile().as_text()
+    assert "sort.level1" in with_scopes and "sort.level1" not in without
+    assert _strip_debug(with_scopes) == _strip_debug(without)

@@ -6,7 +6,7 @@ itself — above all that degenerate inputs (an empty trajectory, an empty
 bench family, a crashed run's non-numeric metric cell) render an explicit
 message instead of crashing or silently emitting nothing.
 """
-from benchmarks.report import attribution, render
+from benchmarks.report import render
 
 _ROW = {"name": "sort", "n": 1 << 20, "s_per_call": 1.0}
 
@@ -43,26 +43,3 @@ def test_fresh_only_row_is_marked_new():
     fresh = {"benches": {"b": [dict(_ROW), {**_ROW, "n": 1 << 10}]}}
     md = render(base, fresh)
     assert "*new*" in md and "1 fresh-only" in md
-
-
-def test_attribution_missing_or_spanless_trace(tmp_path):
-    assert attribution(str(tmp_path / "absent.jsonl")) == ""
-    p = tmp_path / "empty.jsonl"
-    p.write_text("")
-    assert attribution(str(p)) == ""
-    p.write_text('{"type": "metric", "name": "x"}\n')
-    assert attribution(str(p)) == ""
-
-
-def test_attribution_aggregates_spans(tmp_path):
-    p = tmp_path / "t.jsonl"
-    p.write_text(
-        '{"type": "span", "name": "dist.sort", "dur_us": 10.0}\n'
-        '{"type": "span", "name": "dist.sort", "dur_us": 30.0}\n'
-        '{"type": "span", "name": "phase:classify", "dur_us": 5.0}\n'
-    )
-    md = attribution(str(p))
-    lines = [ln for ln in md.splitlines() if ln.startswith("| ")]
-    # phase:* rows sort first despite lower total
-    assert "phase:classify" in lines[1]
-    assert "| dist.sort | 2 | 10.0 | 40.0 |" in md
