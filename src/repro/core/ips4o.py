@@ -304,6 +304,15 @@ def replicated(tree: Any) -> Any:
     return jax.tree.map(one, tree)
 
 
+def _payload_words(values: Any) -> int:
+    """32-bit words a row over the leaves of a payload pytree (leading dim
+    n); a leaf narrower than a word counts as one."""
+    return sum(
+        math.prod(a.shape[1:]) * max(1, a.dtype.itemsize // 4)
+        for a in jax.tree.leaves(values)
+    )
+
+
 def pad_with_sentinel(arrays: Any, unit: int) -> Any:
     """Pad every leaf of the arrays dict to a multiple of ``unit``; pad keys
     get the dtype sentinel so they sort to the tail (the overflow-block
@@ -912,6 +921,11 @@ def ips4o_sort(
     biject keys through ``ops/keyspace.py`` first and are NaN-safe (NaNs
     sort last, -0.0 before +0.0), or canonicalize NaNs yourself before
     calling this low-level engine directly.
+
+    A payload of two or more 32-bit words a row is deferred (DESIGN.md
+    §4.9): the rounds sort (key, int32 row index) and every leaf is
+    gathered once by the sorted index; a one-word payload moves with the
+    keys.  Either way the result is the same, bit for bit.
     """
     n = keys.shape[0]
     if keys.ndim != 1:
@@ -923,6 +937,12 @@ def ips4o_sort(
     if values is not None:
         arrays["v"] = values
     arrays = replicated(arrays)
+    words = 0 if values is None else _payload_words(arrays["v"])
+    deferred = words >= 2
+    if deferred:
+        values = arrays["v"]
+        arrays["v"] = jnp.arange(n, dtype=jnp.int32)
+        obs.count("sort.payload_deferred", words)
 
     unit = max(cfg.base_case, cfg.tile)
     with obs.trace(
@@ -935,6 +955,12 @@ def ips4o_sort(
     out_k = arrays["k"][:n]
     if values is None:
         return out_k
+    if deferred:
+        # stability keeps every pad behind the real keys, sentinel-valued
+        # ones included, so the first n indices are a permutation of [0, n)
+        with obs.layer("sort.payload", words=words), obs.layer("move"):
+            idx = arrays["v"][:n]
+            return out_k, jax.tree.map(lambda a: jnp.take(a, idx, axis=0), values)
     return out_k, jax.tree.map(lambda a: a[:n], arrays["v"])
 
 

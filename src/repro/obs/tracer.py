@@ -41,6 +41,9 @@ Layer names come from one fixed vocabulary, :data:`LAYERS`:
   partition;
 * ``sort.base_case`` — the bucket check and the ``lax.cond`` between the
   windowed base case (branch 0) and ``stable_full_sort`` (branch 1);
+* ``sort.payload`` — the deferred payload (``ips4o_sort`` with two or
+  more payload words a row): every value leaf gathered once by the
+  sorted row index, in a ``move`` scope;
 * child scopes ``sample``, ``classify``, ``partition`` and ``move`` (the
   payload scatters and gathers) inside those.
 
@@ -75,6 +78,7 @@ LAYERS = (
     "sort.segment_ids",
     "sort.level2",
     "sort.base_case",
+    "sort.payload",
     "sample",
     "classify",
     "partition",

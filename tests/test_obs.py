@@ -342,6 +342,7 @@ def test_every_sort_op_carries_a_layer_scope():
         seen |= set(path)
         assert scopes.part(name) is not None, name
     assert {"sort.level1", "sort.segment_ids", "sort.level2", "sort.base_case"} <= seen
+    assert "sort.payload" in seen  # two payload words a row: deferred
     parts = {scopes.part(n) for n in traced}
     assert {"entry", "level1", "level1_move", "segment_ids", "level2", "level2_move",
             "base_case", "fallback", "fallback_move"} <= parts
