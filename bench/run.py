@@ -6,24 +6,26 @@
 Run from the root of a checkout that holds ``BENCHMARK.json``, this
 directory and the program (``src/repro``).  The cell names a configuration
 (``bench/configs/<name>.json``, the file ``BENCHMARK.json`` gives) and a
-traffic mix (``bench/traffic/<name>.json``); its per-layer metrics are
-readers in ``bench/metrics/<name>.py``.  All are found by name, so a cell,
-configuration, mix or metric is added by adding files and entries.
+traffic mix (``bench/traffic/<name>.json``); the configuration may name its
+entry (``"entry"``, ``bench/entries/<name>.py``, by default ``orderby``);
+its per-layer metrics are readers in ``bench/metrics/<name>.py``.  All are
+found by name, so a cell, configuration, entry, mix or metric is added by
+adding files and entries.
 
 A run:
 
-1. set-up: draws the mix's tables from ``--seed`` on the device, every
-   column of each (``tpch.py``), compiles the entry once (JAX's persistent
-   cache at ``<checkout>/.bench_cache/jax``), takes one warm call;
-2. window: calls the entry, ``repro.ops.sort`` of the key column with the
-   row id and every other column as its payload, back to back in a closed
-   loop, one caller waiting on each result, cycling through the tables in
-   an order drawn from the seed, until a call ends ``--seconds`` after the
-   first began; as each call ends its keys and row ids, and its columns at
-   positions drawn from the seed, are copied to the host;
+1. set-up: the entry draws its inputs from ``--seed`` on the cell's chips
+   and builds its call (``load_entry``); the call is compiled once (JAX's
+   persistent cache at ``<checkout>/.bench_cache/jax``), and one warm call
+   is taken and its fetch awaited;
+2. window: calls the entry back to back in a closed loop, one caller
+   waiting on each result, cycling through its inputs in an order drawn
+   from the seed, until a call ends ``--seconds`` after the first began;
+   as each call ends the entry copies what is compared to the host, with
+   positions drawn from the seed;
 3. reads device memory, frees the program, and with ``--trace 1`` reduces
    the profiler trace of the window to the cell's per-layer metrics;
-4. compares every call's output with the plain reference
+4. compares every call's output with the entry's plain reference
    (``reference.py``) and prints each number compared beside its limit.
 
 The last line of standard output is one JSON object: ``correct``,
@@ -55,7 +57,6 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import reference  # noqa: E402
-import tpch  # noqa: E402
 
 # every JAX program of a run is cached here, at a path fixed in the
 # checkout, so that only a cell's first run in a checkout compiles
@@ -93,22 +94,55 @@ def resolve(root: str, workload: str) -> dict:
         m for m in bm["per_layer"]
         if (workload in m["workloads"] if "workloads" in m else m["moves"] in reported)
     ]
+    cfg = load_json(os.path.join(root, config["file"]))
     return {
         "cell": cell,
-        "config": load_json(os.path.join(root, config["file"])),
+        "config": cfg,
+        "entry": load_entry(bench, cfg.get("entry", "orderby")),
         "traffic": load_json(os.path.join(bench, "traffic", cell["traffic"] + ".json")),
         "end_to_end": e2e,
         "per_layer": [(m, load_reader(bench, m["name"])) for m in per_layer],
     }
 
 
-def load_reader(bench: str, name: str):
-    """``bench/metrics/<name>.py``, which defines ``read(trace, ctx)``."""
-    path = os.path.join(bench, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location("bench_metric_" + re.sub(r"\W", "_", name), path)
+def _load(bench: str, kind: str, name: str):
+    path = os.path.join(bench, kind, name + ".py")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_" + re.sub(r"\W", "_", name), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(bench: str, name: str):
+    """``bench/metrics/<name>.py``, which defines ``read(trace, ctx)``."""
+    return _load(bench, "metrics", name).read
+
+
+def load_entry(bench: str, name: str):
+    """``bench/entries/<name>.py``, the program's entry a configuration runs.
+
+    It defines ``validate(config)``, which raises ``ValueError`` for a
+    configuration whose guarantees its reference cannot judge (called
+    before JAX loads), and ``setup(jax, config, traffic, devices, seed)``,
+    which draws the inputs on the cell's ``devices`` and returns a dict:
+
+    - ``inputs``: one tuple of arguments per table; the window cycles
+      through them;
+    - ``call``: the jitted entry, called as ``call(*inputs[t])``;
+    - ``fetch(out, pos)``: what of one call's output is compared, a tuple
+      of device or host arrays, ``pos`` the output positions drawn for it;
+      the window copies each to the host, the warm-up only waits for them;
+    - ``rows``: rows a call orders (``rows_per_s``, ``ctx["rows"]``, the
+      range of ``pos``);
+    - ``in_bytes``: one call's input bytes on each device, in the order of
+      ``devices`` (the denominator of ``hbm_x``);
+    - ``compare(outputs, calls)``: the reference's numbers over
+      ``outputs``, a list of ``(input index, fetched, on the host)`` in call
+      order, and
+      the calls that broke a limit or never came;
+    - ``limits``: each number's limit, compared as ``value <= limit``.
+    """
+    return _load(bench, "entries", name)
 
 
 # -- set-up ----------------------------------------------------------------
@@ -169,26 +203,6 @@ def peak_bytes(stats: dict) -> int:
     return stats["peak_bytes_in_use"] + stats.get("peak_bytes_reserved", 0)
 
 
-def build_entry(jax, traffic: dict):
-    """(jitted entry, jitted pick of sampled output columns).
-
-    The entry is ``repro.ops.sort(key, (rowids, columns), engine=...)``: the
-    library's stable sort, carrying the row id and every other column of
-    the table as its payload, as an engine orders a whole table."""
-    import jax.numpy as jnp
-    from repro import ops
-
-    engine = traffic["engine"]
-
-    def entry(k, rowids, cols):
-        return ops.sort(k, (rowids, cols), engine=engine)
-
-    def pick(cols, pos):
-        return jnp.stack([jnp.take(c, pos) for c in cols])
-
-    return jax.jit(entry), jax.jit(pick)
-
-
 # -- one run ---------------------------------------------------------------
 
 
@@ -208,8 +222,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool, *
     drive a run on the CPU at a small size with the timed path broken."""
     spec = resolve(root, workload)
     config, traffic = dict(spec["config"]), spec["traffic"]
-    if not config["guarantees"]["stable"]:
-        raise ValueError("the reference compares a stable sort; this configuration states none")
+    spec["entry"].validate(config)
     if rows:
         config["rows"] = rows
     chips = spec["cell"]["chips"]
@@ -233,19 +246,17 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool, *
 
 def _run(jax, spec, config, traffic, devices, seed, seconds, trace, tmp,
          process_start, memory, wrap):
-    f, pick = build_entry(jax, traffic)
+    entry = spec["entry"].setup(jax, config, traffic, devices, seed)
+    f, fetch, inputs, n = entry.pop("call"), entry["fetch"], entry["inputs"], entry["rows"]
     if wrap is not None:
         f = wrap(f)
-    dev = devices[0]
-    n = config["rows"]
-    tables = [tpch.table(config, traffic["key"], seed, t, dev) for t in range(traffic["tables"])]
-    rowids = jax.device_put(np.arange(n, dtype=np.int32), dev)
     rng = np.random.default_rng(seed)
-    jax.block_until_ready((tables, rowids))
+    jax.block_until_ready(inputs)
     before = memory(devices)
-    f = f.lower(tables[0][0], rowids, tables[0][1]).compile()  # or loads it from the cache
-    pos0 = jax.device_put(rng.integers(0, n, SAMPLE, dtype=np.int32), dev)
-    jax.block_until_ready(pick(f(tables[0][0], rowids, tables[0][1])[1][1], pos0))  # warms both
+    f = f.lower(*inputs[0]).compile()  # or loads it from the cache
+    # warms both; nothing is copied to the host here (on a TPU v5e a host
+    # copy in the warm-up slowed the window's own copies)
+    jax.block_until_ready(fetch(f(*inputs[0]), rng.integers(0, n, SAMPLE, dtype=np.int32)))
 
     profile_dir = os.path.join(tmp, "profile")
     if trace:
@@ -254,21 +265,19 @@ def _run(jax, spec, config, traffic, devices, seed, seconds, trace, tmp,
     t_start = time.perf_counter()
     setup_s = t_start - process_start
     with jax.profiler.TraceAnnotation("bench.window"):
-        # the tables in an order drawn from the seed, pass after pass,
+        # the inputs in an order drawn from the seed, pass after pass,
         # until a call ends --seconds after the first began
         while not lat or t_done - t_start < seconds:
-            for t in rng.permutation(len(tables)):
+            for t in rng.permutation(len(inputs)):
                 pos = rng.integers(0, n, SAMPLE, dtype=np.int32)
                 t0 = time.perf_counter()
                 with jax.profiler.TraceAnnotation("bench.call"):
-                    out = jax.block_until_ready(f(tables[t][0], rowids, tables[t][1]))
+                    out = jax.block_until_ready(f(*inputs[t]))
                 t_done = time.perf_counter()
                 lat.append(t_done - t0)
                 with jax.profiler.TraceAnnotation("bench.fetch"):
-                    keys, (ids, cols) = out
-                    outputs.append((int(t), np.asarray(keys), np.asarray(ids), pos,
-                                    np.asarray(pick(cols, jax.device_put(pos, dev)))))
-                del out, keys, ids, cols
+                    outputs.append((int(t), tuple(np.asarray(a) for a in fetch(out, pos))))
+                del out
                 if t_done - t_start >= seconds:
                     break
     window_s = t_done - t_start
@@ -279,6 +288,7 @@ def _run(jax, spec, config, traffic, devices, seed, seconds, trace, tmp,
     hlo = f.as_text()
     del f  # the reference runs with the program freed
     calls = len(lat)
+    dev = devices[0]
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices()),
               "memory_peak_bytes": max(peak_bytes(a) for a in after)}
 
@@ -297,28 +307,21 @@ def _run(jax, spec, config, traffic, devices, seed, seconds, trace, tmp,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         extra["breakdown"] = tr.breakdown(ctx["op_names"])
     else:
-        words = tpch.payload_words(config, traffic["key"])
-        in_bytes = n * 4 * (2 + words)  # key, row id and every other column
         values = {
             "rows_per_s": n * calls / window_s,
             "call_ms_p95": percentile(lat, 95) * 1e3,
+            # the fullest device, each against the input bytes it holds
             "hbm_x": max((peak_bytes(a) - b["bytes_in_use"]) / in_bytes
-                         for a, b in zip(after, before)),
+                         for a, b, in_bytes in zip(after, before, entry["in_bytes"])),
             "setup_s": setup_s,
         }
         for m in spec["end_to_end"]:
             metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
 
-    # the reference reads the tables as they were drawn: the key column
-    # whole, the other columns at the rows it puts at each sampled position
-    host_keys = [np.asarray(k) for k, _ in tables]
-
-    def columns_at(t, rows_):
-        return np.asarray(pick(tables[t][1], jax.device_put(rows_, dev)))
-
-    nums, failed = reference.compare(outputs, host_keys, columns_at, calls)
+    nums, failed = entry["compare"](outputs, calls)
+    limits = entry["limits"]
     return {
-        "correct": reference.verdict(nums),
+        "correct": reference.verdict(nums, limits),
         "attempted": calls,
         "failed": failed,
         "metrics": metrics,
@@ -328,7 +331,7 @@ def _run(jax, spec, config, traffic, devices, seed, seconds, trace, tmp,
         "window": {"calls": calls, "seconds": window_s,
                    "call_ms_median": statistics.median(lat) * 1e3,
                    "call_ms": [x * 1e3 for x in lat]},
-        "checks": {k: {"value": nums[k], "limit": lim} for k, lim in reference.LIMITS.items()},
+        "checks": {k: {"value": nums[k], "limit": lim} for k, lim in limits.items()},
     }
 
 
@@ -344,8 +347,8 @@ def main(argv=None) -> int:
     except NoChip as e:
         print(e, file=sys.stderr)
         return 2
-    for line in reference.lines({k: c["value"] for k, c in res["checks"].items()}):
-        print(line, file=sys.stderr)
+    for k, c in res["checks"].items():
+        print(f"{k} {c['value']} limit {c['limit']}", file=sys.stderr)
     print(json.dumps(res), flush=True)
     return 0
 

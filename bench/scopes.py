@@ -9,7 +9,8 @@ started last.  That op's part of the sort is read from its ``op_name``
 innermost ``sort.*`` scope names the layer, a ``move`` scope below it
 makes the time that layer's payload move, and under ``sort.base_case`` the
 ops of the cond's second branch (``cond/branch_1_fun``) are the fallback,
-``stable_full_sort``.  An op whose ``op_name`` holds no ``sort`` scope (an
+``stable_full_sort``.  ``sort.payload`` is the deferred payload's final
+gather, all of it in its ``move`` scope.  An op whose ``op_name`` holds no ``sort`` scope (an
 XLA-made copy, or a program that names no scopes) is unscoped.  Every
 instant of busy time goes to exactly one part, so the parts add up to the
 busy time.
@@ -25,10 +26,12 @@ LAYERS = {
     "sort.segment_ids": "segment_ids",
     "sort.level2": "level2",
     "sort.base_case": "base_case",
+    "sort.payload": "payload",
 }
 # the parts whose payload moves are told apart; a move elsewhere stays
 # with its layer
-MOVES = {"level1": "level1_move", "level2": "level2_move", "fallback": "fallback_move"}
+MOVES = {"level1": "level1_move", "level2": "level2_move", "fallback": "fallback_move",
+         "payload": "payload_move"}
 
 
 def part(op_name: str):

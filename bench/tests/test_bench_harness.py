@@ -1,10 +1,12 @@
 """The on-chip benchmark's harness, checked on the CPU.
 
 Nothing here loads a TPU library or times anything: the table generator,
-the comparison that decides ``correct``, the trace reduction (on a trace
-recorded on a TPU v5e and kept as a fixture), the lookup of cells, mixes
-and metrics by name, the refusal to run without a chip, and whole runs
-driven on the CPU at a small size with the timed path broken
+the comparisons that decide ``correct`` (stable, and over padded shards
+with no tie order), the trace reduction (on a trace recorded on a TPU v5e
+and kept as a fixture), the lookup of cells, mixes, entries and metrics by
+name (a configuration that brings its own entry runs on four virtual CPU
+devices, ``placement.py``), the refusal to run without a chip, and whole
+runs driven on the CPU at a small size with the timed path broken
 (``faults.py``), each of which must come out not correct.
 """
 import hashlib
@@ -218,6 +220,83 @@ def test_sound_output_passes():
     assert reference.verdict(nums) and failed == 0
 
 
+def _sound_shards(cfg, key, seed, d=4):
+    """A table and one call's sound output as an entry that keeps no tie
+    order and returns padded shards hands it over: ties in descending row
+    id (not the stable order), shards of unequal counts padded to one
+    capacity."""
+    keys, payload = host_table(cfg, key, seed)
+    n = keys.shape[0]
+    order = np.lexsort((-np.arange(n), keys)).astype(np.int32)
+    counts = np.full(d, n // d)
+    counts[0] += n - counts.sum() + 5
+    counts[1] -= 5
+    cap = int(counts.max()) + 7
+    k = np.full((d, cap), np.iinfo(np.int32).max, np.int32)
+    r = np.zeros((d, cap), np.int32)
+    for i, (a, c) in enumerate(zip(np.cumsum(counts) - counts, counts)):
+        k[i, :c], r[i, :c] = keys[order[a:a + c]], order[a:a + c]
+    pos = np.random.default_rng(seed).integers(0, n, 256)
+    out = [0, k, r, counts, np.zeros(d, bool), pos, payload[:, order[pos]]]
+
+    def columns_at(t, rows):
+        return payload[:, rows]
+
+    return keys, out, columns_at
+
+
+def test_shard_comparison_accepts_another_tie_order():
+    keys, out, at = _sound_shards(small_config(), "l_shipdate", 16)
+    ids = reference.join(out[2], out[3])
+    assert not np.array_equal(ids, reference.expected(keys)[1])  # not the stable order
+    nums, failed = reference.compare_shards([tuple(out)], [keys], at, 1)
+    assert reference.verdict(nums, reference.SHARD_LIMITS) and failed == 0, nums
+
+
+def _swap_rows(out):
+    # the first row and the last valid one, whose keys differ
+    k, r, counts = out[1], out[2], out[3]
+    last = (len(counts) - 1, counts[-1] - 1)
+    k[0, 0], k[last] = k[last], k[0, 0]
+    r[0, 0], r[last] = r[last], r[0, 0]
+
+
+def _repeat_id(out):
+    out[2][0, 1] = out[2][0, 0]
+
+
+def _column_word_off(out):
+    out[6] = out[6].copy()
+    out[6][-1, 7] ^= 1
+
+
+def _cut_count(out):
+    out[3] = out[3].copy()
+    out[3][1] -= 1
+
+
+def _overflow(out):
+    out[4] = out[4].copy()
+    out[4][2] = True
+
+
+@pytest.mark.parametrize("plant, flagged", [
+    (_swap_rows, {"wrong_keys": 2}),
+    (_repeat_id, {"ids_not_once": 2, "keys_off_their_row": 1}),
+    (_column_word_off, {"wrong_column_words": 1}),
+    (_cut_count, {"missing_rows": 1, "ids_not_once": 1}),
+    (_overflow, {"overflow_shards": 1}),
+], ids=["two_rows_swapped", "repeated_row_id", "column_word_off", "count_cut_by_one",
+        "overflow_flag"])
+def test_shard_comparison_flags_a_planted_fault(plant, flagged):
+    keys, out, at = _sound_shards(small_config(), "l_shipdate", 17)
+    plant(out)
+    nums, failed = reference.compare_shards([tuple(out)], [keys], at, 1)
+    for name, v in flagged.items():
+        assert nums[name] == v, (name, nums)
+    assert failed == 1 and not reference.verdict(nums, reference.SHARD_LIMITS)
+
+
 # -- the trace reduction ------------------------------------------------------
 
 
@@ -305,12 +384,14 @@ def test_per_layer_readers_on_a_recorded_chip_trace():
     assert meta["calls"] == 5 and meta["rows"] == 6_001_215
     ctx = {"calls": meta["calls"], "rows": meta["rows"], "op_names": meta["op_names"],
            "peaks": run.peaks(meta["device_kind"])}
-    got = {name: read(tr, ctx) for name, read in readers.items()}
     want = {k: v["value"] for k, v in meta["metrics"].items()}
     # the trace was recorded before the fallback reader existed, and when
-    # the roofline counted the padded keys of level_fused alone
+    # the roofline counted the padded keys of level_fused alone; readers
+    # added since (the scope.* readers) are checked elsewhere
     want.pop("level_fused_roofline")
-    assert set(got) == set(want) | {"base_case.fallback_share", "level_roofline"}
+    names = set(want) | {"base_case.fallback_share", "level_roofline"}
+    assert names <= set(readers)
+    got = {name: readers[name](tr, ctx) for name in names}
     for name, v in want.items():
         assert got[name] == pytest.approx(v, rel=1e-9), name
     assert got["level_roofline"] == pytest.approx(
@@ -376,6 +457,54 @@ def test_new_config_traffic_and_metric_are_found_by_name(tmp_path):
     # the committed cell does not report the new cell's metric
     names = [m["name"] for m, _ in run.resolve(root, "lineitem-sf1.partkey")["per_layer"]]
     assert "test.answer" not in names
+
+
+def test_new_entry_reference_and_four_chip_cell_are_added_by_new_files(tmp_path):
+    """A configuration that brings its own entry (``dist.sort`` of a table
+    sharded over a ``(4,)`` mesh, fixtures/dist_orderby.py) and states no
+    tie order, its mix and a four-chip cell, added to a copy of the
+    checkout without editing a file under bench/, run on four virtual CPU
+    devices: correct by the shard comparison, every input spread over the
+    four devices."""
+    root = checkout(tmp_path)
+    before = _digest(root)
+    bench = os.path.join(root, "bench")
+    shutil.copy(os.path.join(HERE, "fixtures", "dist_orderby.py"), os.path.join(bench, "entries"))
+    cfg = small_config()
+    cfg.update(name="tiny-lineitem-mesh", entry="dist_orderby", chips=4, mesh={"data": 4})
+    cfg["guarantees"] = dict(cfg["guarantees"], stable=False, stable_means="no tie order")
+    with open(os.path.join(bench, "configs", "tiny-lineitem-mesh.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(bench, "traffic", "dist-shipdate.json"), "w") as f:
+        json.dump({"loop": "closed", "clients": 1, "key": "l_shipdate", "engine": "xla",
+                   "tables": 2, "why": "ties in every call, no tie order kept"}, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bm = json.load(f)
+    bm["configs"].append({"name": "tiny-lineitem-mesh", "source": "a test",
+                          "file": "bench/configs/tiny-lineitem-mesh.json", "reduced": ["rows"],
+                          "why": "a test"})
+    bm["workloads"].append({"name": "tiny-mesh.shipdate", "config": "tiny-lineitem-mesh",
+                            "traffic": "dist-shipdate", "chips": 4, "why": "a test"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bm, f)
+    after = _digest(root)
+    assert {k: v for k, v in after.items() if k in before} == before  # nothing edited
+
+    rows = 8192
+    p = subprocess.run(
+        [sys.executable, os.path.join(HERE, "placement.py"), root, "tiny-mesh.shipdate",
+         str(rows)],
+        cwd=root, env=dict(cpu_env(), XLA_FLAGS="--xla_force_host_platform_device_count=4"),
+        capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    res = json.loads(p.stdout.strip().splitlines()[-1])
+    assert res["correct"] and res["failed"] == 0 and res["attempted"] >= 1, res
+    assert set(res["checks"]) == set(reference.SHARD_LIMITS)
+    # key, row id and 37 column words, each a quarter of the table on each device
+    assert len(res["placement"]) == 39
+    for shards in res["placement"]:
+        assert sorted(dev for dev, _ in shards) == [0, 1, 2, 3]
+        assert all(shape == [rows // 4] for _, shape in shards)
 
 
 # -- refusing to run ------------------------------------------------------------

@@ -39,6 +39,8 @@ OP_NAMES = {
     "fusion.14": f"{E}/jit(_take)/gather;{E}/sort.level1/partition/move/scatter",
     "fusion.15": f"{E}/sort.level2/sort.segment_ids/jit(searchsorted)/while/body/gather",
     "fusion.16": f"{E}/sort.base_case/cond/branch_0_fun/move/vmap()/gather",
+    "fusion.17": f"{E}/sort.payload/move/jit(_take)/gather",
+    "fusion.18": f"{E}/sort.payload/slice",
 }
 
 
@@ -60,6 +62,8 @@ def _trace():
         ["copy.13", 300, 20, "copy"],             # unscoped 20; idle 320..400
         ["fusion.14", 400, 10, "fusion"],         # two merged names, the first: entry 10
         ["fusion.16", 410, 10, "fusion"],         # branch 0's move: base_case 10
+        ["fusion.17", 420, 10, "fusion"],         # the final gather: payload_move 10
+        ["fusion.18", 430, 5, "fusion"],          # outside its move: payload 5
     ]
     dev1 = [["level_fused.2", 0, 40, "custom-call"],   # level1 40
             ["copy.13", 40, 20, "copy"]]              # unscoped 20
@@ -76,7 +80,7 @@ def _readers():
 WANT_NS = {
     "entry": 10 + 10, "level1": 20 + 40, "level1_move": 15, "segment_ids": 5 + 40 + 10 + 10,
     "level2": 30, "level2_move": 30, "base_case": 5 + 5 + 10 + 10, "fallback": 50,
-    "fallback_move": 60,
+    "fallback_move": 60, "payload": 5, "payload_move": 10,
 }
 
 
@@ -94,6 +98,9 @@ def test_parts_of_op_names():
     assert part(OP_NAMES["fusion.16"]) == "base_case"     # a move of branch 0 stays there
     assert part(OP_NAMES["conditional.10"]) == "base_case"
     assert part(f"{E}/sort.level1/move/sort") == "level1_move"
+    # the deferred payload's gather is its own part, no longer the entry's
+    assert part(OP_NAMES["fusion.17"]) == "payload_move"
+    assert part(OP_NAMES["fusion.18"]) == "payload"
     assert part("jit(entry)/jit(argsort)/sort") is None   # no scope named
     assert part("jit(entry)/sort") is None                 # an op named sort, not the scope
     assert part("gather") is None and part("") is None
@@ -106,7 +113,7 @@ def test_readers_on_a_hand_built_trace():
     for p, ns in WANT_NS.items():
         assert got[f"scope.{p}_ms"] == pytest.approx(ns / 2 / 2 * 1e-6), p
     busy_ns = tr.busy_s() * 1e9 * 2  # summed over the devices
-    assert busy_ns == pytest.approx(320 + 20 + 60)
+    assert busy_ns == pytest.approx(320 + 15 + 20 + 60)
     assert got["scope.unscoped_share"] == pytest.approx(100 * (20 + 20) / busy_ns)
     # the parts and the unscoped time add up to the busy time
     layers_ms = sum(v for k, v in got.items() if k.endswith("_ms"))
