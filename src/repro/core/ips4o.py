@@ -251,13 +251,20 @@ def _classify_rows(n: int, cfg: SortConfig, dtype, k: int) -> int:
 
 
 def segment_ids(offsets: jax.Array, n: int) -> jax.Array:
-    """Per-position bucket/segment id from (nb+1,) boundary offsets."""
-    return (
-        jnp.searchsorted(offsets, jnp.arange(n, dtype=jnp.int32), side="right").astype(
-            jnp.int32
-        )
-        - 1
-    )
+    """Per-position bucket/segment id from (nb+1,) sorted boundary offsets.
+
+    Position p gets the number of offsets <= p, minus one, which is
+    ``searchsorted(offsets, p, side="right") - 1`` bit for bit, in
+    O(n + nb): a 1 is scatter-added at each offset and the marks are
+    summed up.  Offsets >= n are dropped (no position counts them) and
+    offsets below 0 count at position 0 (every position counts them);
+    repeated offsets (empty buckets) add up at one position; positions
+    before a first offset above 0 get -1.  The offsets stay sorted once
+    clamped, so the scatter is told so and XLA does not sort them again.
+    """
+    at = jnp.maximum(offsets, 0)
+    marks = jnp.zeros((n,), jnp.int32).at[at].add(1, mode="drop", indices_are_sorted=True)
+    return jnp.cumsum(marks, dtype=jnp.int32) - 1
 
 
 def _window_perm(keys_w: jax.Array, fb_w: jax.Array) -> jax.Array:

@@ -35,8 +35,9 @@ Layer names come from one fixed vocabulary, :data:`LAYERS`:
   splitter RNG, decode and the final slice;
 * ``sort.level1`` — level 1 (``level_pass``): sample, classify (the fused
   Pallas kernel and its prefix epilogue on that engine), partition;
-* ``sort.segment_ids`` — the bucket id of every position, searched in a
-  level's offsets (``segment_ids``), before level 2 and the base case;
+* ``sort.segment_ids`` — the bucket id of every position, counted from a
+  scatter of a level's offsets and a cumsum (``segment_ids``), before
+  level 2 and the base case;
 * ``sort.level2`` — level 2 (``segmented_level_pass``): sample, classify,
   partition;
 * ``sort.base_case`` — the bucket check and the ``lax.cond`` between the
