@@ -48,7 +48,7 @@ assert not bool(jnp.any(overflow))
 print(f"5. distributed sort: {int(counts.sum())} elements globally ordered "
       f"across {mesh.shape['data']} shard(s)")
 
-# 6. Batched: (B, n) rows sorted in ONE trace (no vmap, no python loop) ----
+# 6. Batched: (B, n) rows sorted in ONE trace (no python loop) --------------
 from repro.ops import batched_sort, batched_topk
 
 xb = jnp.asarray(np.random.default_rng(1).random((8, 1 << 14), np.float32))

@@ -34,7 +34,6 @@ from repro.classify.learned import (
     eval_cdf_buckets,
     fit_cdf_knots,
     learned_bucket_ids,
-    learned_bucket_ids_batched,
     sample_imbalance,
 )
 from repro.classify.radix import radix_bucket_ids, radix_shift
@@ -46,7 +45,6 @@ from repro.classify.router import (
 )
 from repro.classify.tree import (
     classify,
-    classify_batched,
     classify_segmented,
     num_local_buckets,
 )
@@ -55,7 +53,6 @@ __all__ = [
     "CLASSIFIERS",
     # tree
     "classify",
-    "classify_batched",
     "classify_segmented",
     "num_local_buckets",
     # radix
@@ -68,7 +65,6 @@ __all__ = [
     "eval_cdf_buckets",
     "sample_imbalance",
     "learned_bucket_ids",
-    "learned_bucket_ids_batched",
     # router
     "resolve_classifier",
     "distribution_moments",

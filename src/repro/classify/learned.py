@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.classify.tree import classify, classify_batched
+from repro.classify.tree import classify
 from repro.core.sampling import sentinel_for
 
 __all__ = [
@@ -54,7 +54,6 @@ __all__ = [
     "eval_cdf_buckets",
     "sample_imbalance",
     "learned_bucket_ids",
-    "learned_bucket_ids_batched",
 ]
 
 # P: piecewise-linear segments of the CDF.  Few segments keep the fit and
@@ -78,7 +77,7 @@ def _to_float(x: jax.Array) -> jax.Array:
 
 
 def fit_cdf_knots(sorted_sample: jax.Array, num_knots: int = NUM_KNOTS) -> jax.Array:
-    """(..., m) sorted sample -> (..., P+1) f32 knots at sample quantiles."""
+    """(m,) sorted sample -> (P+1,) f32 knots at sample quantiles."""
     m = sorted_sample.shape[-1]
     idx = np.clip(
         np.round(np.arange(num_knots + 1) * (m - 1) / max(num_knots, 1)), 0, m - 1
@@ -87,23 +86,14 @@ def fit_cdf_knots(sorted_sample: jax.Array, num_knots: int = NUM_KNOTS) -> jax.A
 
 
 def eval_cdf_buckets(keys: jax.Array, knots: jax.Array, k: int) -> jax.Array:
-    """Bucket index j in [0, k) per key — the model evaluation.
-
-    ``keys`` (n,) with knots (P+1,), or (B, n) with per-row knots (B, P+1).
-    """
+    """Bucket index j in [0, k) per key (n,) under knots (P+1,) — the
+    model evaluation."""
     P = knots.shape[-1] - 1
     kf = _to_float(keys)
-    inner = knots[..., 1:-1]  # (.., P-1) interior knots
-    if keys.ndim == 2:
-        seg = jax.vmap(lambda kn, kv: jnp.searchsorted(kn, kv, side="right"))(
-            inner, kf
-        ).astype(jnp.int32)
-        lo = jnp.take_along_axis(knots, seg, axis=-1)
-        hi = jnp.take_along_axis(knots, seg + 1, axis=-1)
-    else:
-        seg = jnp.searchsorted(inner, kf, side="right").astype(jnp.int32)
-        lo = jnp.take(knots, seg, axis=0)
-        hi = jnp.take(knots, seg + 1, axis=0)
+    inner = knots[1:-1]  # (P-1,) interior knots
+    seg = jnp.searchsorted(inner, kf, side="right").astype(jnp.int32)
+    lo = jnp.take(knots, seg, axis=0)
+    hi = jnp.take(knots, seg + 1, axis=0)
     # duplicate knots (heavy sample duplicates) give hi == lo: the segment
     # carries zero probability mass, frac pins to 0 — still monotone
     span = hi - lo
@@ -118,7 +108,7 @@ def eval_cdf_buckets(keys: jax.Array, knots: jax.Array, k: int) -> jax.Array:
 
 def sample_imbalance(sorted_sample: jax.Array, knots: jax.Array, k: int) -> jax.Array:
     """Largest predicted bucket load on the training sample, normalised so
-    a perfect fit scores 1.0 (scalar per row; (...,) for batched input).
+    a perfect fit scores 1.0 (a scalar).
 
     The model is monotone and the sample sorted, so the predicted bucket
     ids are sorted too and per-bucket counts are rank differences — no
@@ -127,12 +117,8 @@ def sample_imbalance(sorted_sample: jax.Array, knots: jax.Array, k: int) -> jax.
     m = sorted_sample.shape[-1]
     jb = eval_cdf_buckets(sorted_sample, knots, k)
     edges = jnp.arange(k + 1, dtype=jnp.int32)
-    if sorted_sample.ndim == 2:
-        pos = jax.vmap(lambda r: jnp.searchsorted(r, edges, side="left"))(jb)
-    else:
-        pos = jnp.searchsorted(jb, edges, side="left")
-    counts = jnp.diff(pos)
-    return jnp.max(counts, axis=-1).astype(jnp.float32) * k / m
+    counts = jnp.diff(jnp.searchsorted(jb, edges, side="left"))
+    return jnp.max(counts).astype(jnp.float32) * k / m
 
 
 def _with_eq(keys: jax.Array, j: jax.Array) -> jax.Array:
@@ -152,35 +138,15 @@ def learned_bucket_ids(
     ``sorted_sample`` (m,) trains the CDF; ``splitters`` (k-1,) are the
     tree's equidistant order statistics of the *same* sample, so the
     ``lax.cond`` fallback branch needs no extra sampling pass.  Returns
-    (bucket ids, fell_back flag).
+    (bucket ids, fell_back flag).  Under ``jax.vmap`` (the batched sort)
+    the flag is per row and the ``cond`` becomes a ``select`` that runs
+    both classifiers.
     """
     knots = fit_cdf_knots(sorted_sample)
     fell_back = sample_imbalance(sorted_sample, knots, k) > threshold
     b = jax.lax.cond(
         fell_back,
         lambda: classify(keys, splitters, k),
-        lambda: _with_eq(keys, eval_cdf_buckets(keys, knots, k)),
-    )
-    return b, fell_back
-
-
-def learned_bucket_ids_batched(
-    keys: jax.Array,
-    sorted_sample: jax.Array,
-    splitters: jax.Array,
-    k: int,
-    threshold: float = IMBALANCE_THRESHOLD,
-) -> Tuple[jax.Array, jax.Array]:
-    """Per-row ids for ``keys`` (B, n) with per-row samples (B, m) and
-    splitters (B, k-1).  The fallback is batch-wide (one ``lax.cond`` for
-    the whole trace, like the batched robustness fallback — DESIGN.md §6):
-    a single badly-fit row reroutes every row through the tree.
-    """
-    knots = fit_cdf_knots(sorted_sample)
-    fell_back = jnp.any(sample_imbalance(sorted_sample, knots, k) > threshold)
-    b = jax.lax.cond(
-        fell_back,
-        lambda: classify_batched(keys, splitters, k),
         lambda: _with_eq(keys, eval_cdf_buckets(keys, knots, k)),
     )
     return b, fell_back

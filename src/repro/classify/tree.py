@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from repro.core.sampling import build_tree, sentinel_for
 
-__all__ = ["classify", "classify_batched", "classify_segmented", "num_local_buckets"]
+__all__ = ["classify", "classify_segmented", "num_local_buckets"]
 
 
 def num_local_buckets(k: int) -> int:
@@ -52,31 +52,6 @@ def classify(keys: jax.Array, splitters: jax.Array, k: int) -> jax.Array:
         idx = 2 * idx + (keys > node).astype(jnp.int32)
     j = idx - k
     eq = (keys == jnp.take(upper, j, axis=0)).astype(jnp.int32)
-    return 2 * j + eq
-
-
-def classify_batched(keys: jax.Array, splitters: jax.Array, k: int) -> jax.Array:
-    """Per-row classification over a leading batch dimension (DESIGN.md §6).
-
-    ``keys`` (B, n) rows classify against their own sorted splitter set
-    ``splitters`` (B, k-1): the same branch-free descent as :func:`classify`
-    with the tree/upper lookups row-local (``take_along_axis``).  Returns
-    int32 local bucket ids (B, n) in [0, 2k).
-    """
-    tree = build_tree(splitters, k)  # (B, k)
-    upper = jnp.concatenate(
-        [
-            splitters,
-            jnp.full((splitters.shape[0], 1), sentinel_for(keys.dtype), keys.dtype),
-        ],
-        axis=1,
-    )  # (B, k)
-    idx = jnp.ones(keys.shape, jnp.int32)
-    for _ in range(int(math.log2(k))):
-        node = jnp.take_along_axis(tree, idx, axis=1)
-        idx = 2 * idx + (keys > node).astype(jnp.int32)
-    j = idx - k
-    eq = (keys == jnp.take_along_axis(upper, j, axis=1)).astype(jnp.int32)
     return 2 * j + eq
 
 

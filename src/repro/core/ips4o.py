@@ -67,21 +67,19 @@ import numpy as np
 from repro import obs
 from repro.classify import (
     classify,
-    classify_batched,
     classify_segmented,
     learned_bucket_ids,
-    learned_bucket_ids_batched,
     radix_bucket_ids,
     resolve_classifier,
 )
 from repro.core import sampling
-from repro.core.partition import ENGINES, batched_stable_partition, stable_partition
+from repro.core.partition import ENGINES, stable_partition
 from repro.kernels import resolve_interpret
 
 __all__ = [
     "SortConfig",
     "ips4o_sort",
-    "is4o_sort",
+    "ips4o_sort_batched",
     "plan_levels",
     "fit_window",
     "make_sorter",
@@ -92,20 +90,11 @@ __all__ = [
     "segmented_level_pass",
     "partition_passes",
     "base_case",
+    "base_case_or_fallback",
     "bucket_violations",
     "segment_ids",
     "stable_full_sort",
     "tiebreak_passes",
-    # batch-axis-native pipeline, consumed by ``repro.ops.batched`` (§6)
-    "ips4o_sort_batched",
-    "batched_pad_with_sentinel",
-    "batched_level_pass",
-    "batched_segmented_level_pass",
-    "batched_partition_passes",
-    "batched_base_case",
-    "batched_bucket_violations",
-    "batched_segment_ids",
-    "batched_stable_full_sort",
 ]
 
 
@@ -179,10 +168,10 @@ def _obs_level_stats(offsets, nb: int, pad_bucket: Optional[int], level: str) ->
     functions of the partition offsets, delivered through the obs side
     channel (unordered debug callback — ``repro.obs``, DESIGN.md §12).
     Stages nothing — zero added jaxpr equations — unless obs is enabled
-    at trace time.  Accepts (nb+1,) and batched (B, nb+1) offsets."""
+    at trace time.  Under the batch lift each row observes its own."""
     if not obs.enabled():
         return
-    sizes = jnp.diff(offsets, axis=-1)
+    sizes = jnp.diff(offsets)
     ids = np.arange(nb)
     mask = ids % 2 == 0  # odd ids = equality buckets, sized by the data
     if pad_bucket is not None:
@@ -190,10 +179,9 @@ def _obs_level_stats(offsets, nb: int, pad_bucket: Optional[int], level: str) ->
     k_eff = int(mask.sum())
     if k_eff == 0:
         return
-    rows = int(np.prod(sizes.shape[:-1], dtype=np.int64)) if sizes.ndim > 1 else 1
     szs = jnp.where(jnp.asarray(mask), sizes, 0)
     largest = jnp.max(szs)
-    mean = jnp.maximum(jnp.sum(szs) / (k_eff * max(rows, 1)), 1.0)
+    mean = jnp.maximum(jnp.sum(szs) / k_eff, 1.0)
     obs.jit_observe(
         "sort.bucket_imbalance", largest.astype(jnp.float32) / mean, level=level
     )
@@ -560,7 +548,7 @@ def bucket_violations(
     nb: int,
     W: int,
     pad_bucket: Optional[int] = None,
-    limit: Optional[jax.Array] = None,
+    limit: Optional[int] = None,
 ) -> jax.Array:
     """True iff some non-trivial bucket exceeds W/2 (base-case precondition).
 
@@ -578,329 +566,79 @@ def bucket_violations(
     return jnp.any(jnp.where(nontrivial, sizes, 0) > W // 2)
 
 
-def _sort_padded(arrays: Any, n_real: int, cfg: SortConfig, levels: Sequence[int]) -> Any:
-    """Sort padded arrays dict (pads = sentinel keys at the tail)."""
-    n = arrays["k"].shape[0]
-    W = cfg.base_case
+def _per_row(f):
+    """The identity lift: the stages already take one (n,) row."""
+    return f
 
+
+def _sort_padded(
+    arrays: Any,
+    n_real: int,
+    cfg: SortConfig,
+    levels: Sequence[int],
+    limit: Optional[int] = None,
+    lift=_per_row,
+) -> Any:
+    """Sort a padded arrays dict (pads = sentinel keys at each row's tail).
+
+    The one pipeline of the level passes, the base case and the fallback.
+    Every stage is written for one (n,) row; ``lift`` carries a per-row
+    function over the rows: the identity for one row (``ips4o_sort``,
+    ``ops.topk``), ``jax.vmap`` for (B, n) rows (``ips4o_sort_batched``,
+    ``ops.batched``).  ``limit`` (static, multiple of W) sorts only the
+    buckets that meet [0, limit), for the partial sorts of ``ops.topk``.
+    """
     if not levels:
         # Single window: plain stable base case (the paper's smallSort).
-        return stable_full_sort(arrays)
+        return lift(stable_full_sort)(arrays)
 
-    arrays, offsets, nb, pad_bucket = partition_passes(arrays, n_real, cfg, levels)
+    layout = []  # (nb, pad_bucket): static ints, the same in every row
 
-    # ---- Base case + robustness fallback ---------------------------------
+    def passes(arrays):
+        arrays, offsets, nb, pad_bucket = partition_passes(arrays, n_real, cfg, levels)
+        layout.extend((nb, pad_bucket))
+        return arrays, offsets
+
+    arrays, offsets = lift(passes)(arrays)
+    nb, pad_bucket = layout
+    return base_case_or_fallback(
+        arrays, offsets, nb, pad_bucket, cfg, lift(stable_full_sort), limit, lift
+    )
+
+
+def base_case_or_fallback(
+    arrays: Any,
+    offsets: jax.Array,
+    nb: int,
+    pad_bucket: Optional[int],
+    cfg: SortConfig,
+    fallback,
+    limit: Optional[int] = None,
+    lift=_per_row,
+) -> Any:
+    """The base case over partitioned rows, or ``fallback`` (an already
+    lifted function of ``arrays``) where a bucket breaks its precondition.
+
+    The one ``lax.cond`` stays outside the lift: a ``vmap`` of a ``cond``
+    on a batched predicate becomes a ``select`` that runs both branches.
+    So each row checks its own buckets, ``violated`` is the ``any`` of
+    those flags, and a single violating row sends the whole batch through
+    ``fallback`` (DESIGN.md §6.2).
+    """
+    n = arrays["k"].shape[-1]
+    W = cfg.base_case
     with obs.layer("sort.segment_ids"):
-        fb = segment_ids(offsets, n)
+        fb = lift(lambda off: segment_ids(off, n))(offsets)
     # the scope wraps the cond from outside, so its branches' op_names
     # keep ``cond/branch_<i>_fun/jit(argsort)`` as they were
     with obs.layer("sort.base_case", W=W, fallback=cfg.fallback):
-        violated = bucket_violations(offsets, nb, W, pad_bucket)
+        flags = lift(lambda off: bucket_violations(off, nb, W, pad_bucket, limit))(offsets)
+        violated = jnp.any(flags) if flags.ndim else flags
         _obs_base_stats(violated)
+        run = lift(lambda a, fb: base_case(a, fb, W, limit))
         if cfg.fallback:
-            return jax.lax.cond(
-                violated,
-                stable_full_sort,
-                lambda a: base_case(a, fb, W),
-                arrays,
-            )
-        return base_case(arrays, fb, W)
-
-
-# --------------------------------------------------------------------------
-# Batch-axis-native pipeline (DESIGN.md §6): every stage of the 1-D sort
-# lifted over a leading batch dimension (B, n) in ONE trace.  Rows never
-# exchange elements; each row gets its own splitter set, its own bucket
-# offsets, and its own stable partition.  The Pallas engine runs the
-# batch-grid kernels (grid = (B, tiles)); the XLA engine vmaps its dense
-# formulation, which batches natively.
-
-
-def batched_segment_ids(offsets: jax.Array, n: int) -> jax.Array:
-    """Per-position bucket id per row from (B, nb+1) boundary offsets."""
-    return jax.vmap(lambda off: segment_ids(off, n))(offsets)
-
-
-def batched_stable_full_sort(arrays: Any) -> Any:
-    """Per-row stable sort by key — the batched robustness fallback."""
-    order = jnp.argsort(arrays["k"], axis=1, stable=True)
-    take = jax.vmap(lambda a, p: jnp.take(a, p, axis=0))
-    return jax.tree.map(lambda a: take(a, order), arrays)
-
-
-def batched_pad_with_sentinel(arrays: Any, unit: int) -> Any:
-    """Pad axis 1 of every (B, n, ...) leaf to a multiple of ``unit``; pad
-    keys get the dtype sentinel (each row's overflow-block analogue)."""
-    n = arrays["k"].shape[1]
-    n_pad = -(-n // unit) * unit
-    if n_pad == n:
-        return arrays
-    pad_n = n_pad - n
-    sent = sampling.sentinel_for(arrays["k"].dtype)
-
-    def pad(a):
-        padding = [(0, 0), (0, pad_n)] + [(0, 0)] * (a.ndim - 2)
-        return jnp.pad(a, padding)
-
-    arrays = jax.tree.map(pad, arrays)
-    arrays["k"] = arrays["k"].at[:, n:].set(sent)
-    return arrays
-
-
-def batched_base_case(
-    arrays: Any, fb: jax.Array, W: int, limit: Optional[int] = None
-) -> Any:
-    """The two overlapped window-sort passes (§4.3) over (B, n, ...) leaves.
-
-    Rows share no window: the per-row index range [lo, hi) reshapes to
-    B * (hi-lo)/W independent windows, so the same ``_window_perm``
-    machinery sorts every row's windows in one pass.  ``limit`` (static,
-    multiple of W) restricts both passes to [0, limit) *per row*.
-    """
-    B = fb.shape[0]
-    n = fb.shape[1] if limit is None else limit
-
-    def one_pass(arrays, fb, lo, hi):
-        m = hi - lo
-        nw = B * (m // W)
-        kw = arrays["k"][:, lo:hi].reshape(nw, W)
-        fw = fb[:, lo:hi].reshape(nw, W)
-        perm = _window_perm(kw, fw)
-
-        def fix(a):
-            aw = a[:, lo:hi].reshape((nw, W) + a.shape[2:])
-            sw = _apply_window_perm(perm, aw).reshape((B, m) + a.shape[2:])
-            return a.at[:, lo:hi].set(sw)
-
-        arrays = jax.tree.map(fix, arrays)
-        fb = fb.at[:, lo:hi].set(_apply_window_perm(perm, fw).reshape(B, m))
-        return arrays, fb
-
-    arrays, fb = one_pass(arrays, fb, 0, n)
-    if n > W:  # offset pass: per-row windows at W/2
-        arrays, fb = one_pass(arrays, fb, W // 2, n - W // 2)
-    return arrays
-
-
-def batched_bucket_violations(
-    offsets: jax.Array,
-    nb: int,
-    W: int,
-    pad_bucket: Optional[int] = None,
-    limit: Optional[jax.Array] = None,
-) -> jax.Array:
-    """True iff ANY row has a non-trivial bucket exceeding W/2.  The
-    fallback is batch-wide (one ``lax.cond`` for the whole trace), so a
-    single violating row reroutes every row through the stable sort."""
-    sizes = jnp.diff(offsets, axis=1)  # (B, nb)
-    ids = jnp.arange(nb, dtype=jnp.int32)
-    nontrivial = (ids % 2) == 0
-    if pad_bucket is not None:
-        nontrivial = nontrivial & (ids != pad_bucket)
-    nontrivial = jnp.broadcast_to(nontrivial[None, :], sizes.shape)
-    if limit is not None:
-        nontrivial = nontrivial & (offsets[:, :-1] < limit)
-    return jnp.any(jnp.where(nontrivial, sizes, 0) > W // 2)
-
-
-def batched_level_pass(
-    arrays: Any, n_real: int, k: int, cfg: SortConfig, rng: jax.Array
-) -> Tuple[Any, jax.Array, int, int]:
-    """One global level pass per row: per-row sample -> per-row splitters ->
-    batched branchless classify -> per-row stable partition.
-
-    Returns (arrays, offsets (B, nb+1), nb, pad_bucket) with nb = 2k + 1.
-    On the "pallas" engine the whole level runs as ONE batch-grid launch
-    of the fused level kernel (``kernels.level_fused``) for all B rows.
-
-    Classifier dispatch mirrors ``level_pass``: "radix" skips the per-row
-    sampling entirely (the shift mask is row-independent), "learned" fits
-    one CDF model per row and falls back batch-wide to the per-row trees
-    when any row's measured imbalance trips the threshold, "auto" at this
-    depth means "tree" (the data-aware router is eager-side).
-    """
-    keys = arrays["k"]
-    B, n = keys.shape
-    clf = resolve_classifier(cfg.classifier)
-    nb = 2 * k + 1  # +1: dedicated pad bucket per row
-    pad_n = n - n_real
-    engine = resolve_engine(cfg, n, keys.dtype)
-    rows = (
-        _classify_rows(n, cfg, keys.dtype, k)
-        if engine == "pallas" and clf in ("tree", "radix")
-        else 0
-    )
-    interpret = resolve_interpret()
-
-    if clf != "radix":
-        with obs.trace("sample", batched=True, k=k, n=n_real):
-            m1 = min(
-                max(sampling.oversampling_factor(n_real) * k, k), cfg.max_sample, n_real
-            )
-            row_rngs = jax.random.split(rng, B)
-            sample_pos = jax.vmap(lambda r: jax.random.randint(r, (m1,), 0, n_real))(
-                row_rngs
-            )
-            sample = jnp.sort(jnp.take_along_axis(keys, sample_pos, axis=1), axis=1)
-            spl = sampling.select_splitters(sample, k)  # (B, k-1) per-row splitters
-
-    if rows:
-        # one batch-grid launch of the fused level kernel for all B rows
-        from repro.kernels.level_fused import level_fused_batched
-
-        with obs.trace("classify", batched=True, engine="pallas", fused=True, k=k):
-            dest, off = level_fused_batched(
-                keys, None if clf == "radix" else spl, k=k, n_real=n_real,
-                classifier=clf, rows=rows, interpret=interpret,
-            )
-        with obs.trace("partition", batched=True, engine="pallas", fused=True, nb=nb):
-            flat_dest = (
-                dest + n * jnp.arange(B, dtype=jnp.int32)[:, None]
-            ).reshape(-1)
-
-            def move(a):
-                fa = a.reshape((B * n,) + a.shape[2:])
-                out = jnp.zeros_like(fa).at[flat_dest].set(
-                    fa, mode="promise_in_bounds"
-                )
-                return out.reshape(a.shape)
-
-            return jax.tree.map(move, arrays), off, nb, 2 * k
-    with obs.trace("classify", batched=True, engine=engine, classifier=clf, k=k):
-        if clf == "radix":
-            b = radix_bucket_ids(keys, k)
-        elif clf == "learned":
-            b, _ = learned_bucket_ids_batched(keys, sample, spl, k)
-        else:
-            b = classify_batched(keys, spl, k)
-        if pad_n:
-            is_pad = jnp.arange(n, dtype=jnp.int32)[None, :] >= n_real
-            b = jnp.where(is_pad, 2 * k, b)
-    with obs.trace("partition", batched=True, engine=engine, nb=nb):
-        arrays, off = batched_stable_partition(
-            b, arrays, nb, _auto_tile(n, nb, cfg), engine=engine,
-            interpret=interpret,
-        )
-    return arrays, off, nb, 2 * k
-
-
-def batched_segmented_level_pass(
-    arrays: Any,
-    seg_offsets: jax.Array,
-    num_seg: int,
-    n_real: int,
-    k: int,
-    cfg: SortConfig,
-    rng: jax.Array,
-    sample_cap: int = 2048,
-    classifier: str = "tree",
-    consumed_bits: int = 0,
-) -> Tuple[Any, jax.Array, int]:
-    """Recursion level 2 per row: per-(row, segment) splitters, flattened
-    classification, per-row composite-bucket partition.
-
-    ``seg_offsets`` (B, num_seg+1) bounds each row's segments.  The
-    composite id ``seg * 2k + local`` stays row-local, so the partition is
-    the per-row one (nb = num_seg * 2k buckets per row) — rows still never
-    exchange elements.
-
-    ``classifier`` accepts "tree" or "radix" under the same contract as the
-    1-D ``segmented_level_pass``: radix is only valid when level 1 was
-    radix (bit-aligned segments), and it skips the per-(row, segment)
-    sampling entirely.
-    """
-    keys = arrays["k"]
-    B, n = keys.shape
-    seg = batched_segment_ids(seg_offsets, n)  # (B, n)
-    if classifier == "radix":
-        local = radix_bucket_ids(keys, k, consumed_bits)
-    else:
-        m = min(max(sampling.oversampling_factor(n_real) * k, k), sample_cap)
-        seg_rngs = jax.random.split(rng, B * num_seg).reshape(B, num_seg, -1)
-        pos = jax.vmap(
-            jax.vmap(lambda r, lo, hi: sampling.sample_indices(r, m, lo, hi))
-        )(seg_rngs, seg_offsets[:, :-1], seg_offsets[:, 1:])  # (B, num_seg, m)
-        svals = jnp.sort(
-            jnp.take_along_axis(keys, pos.reshape(B, num_seg * m), axis=1).reshape(
-                B, num_seg, m
-            ),
-            axis=-1,
-        )
-        spl = sampling.select_splitters(svals, k)  # (B, num_seg, k-1)
-        # flatten (row, segment) -> global segment for the shared classifier
-        gseg = (seg + num_seg * jnp.arange(B, dtype=jnp.int32)[:, None]).reshape(B * n)
-        local = classify_segmented(
-            keys.reshape(B * n), gseg, spl.reshape(B * num_seg, k - 1), k
-        ).reshape(B, n)
-    comp = seg * (2 * k) + local  # row-local composite bucket
-    nb = num_seg * 2 * k
-    engine = resolve_engine(cfg, n, keys.dtype)
-    if engine == "pallas" and nb > _PALLAS_NB_MAX:
-        engine = "xla"
-    arrays, offsets = batched_stable_partition(
-        comp, arrays, nb, _auto_tile(n, nb, cfg), engine=engine
-    )
-    return arrays, offsets, nb
-
-
-def batched_partition_passes(
-    arrays: Any, n_real: int, cfg: SortConfig, levels: Sequence[int]
-) -> Tuple[Any, jax.Array, int, Optional[int]]:
-    """The (at most two) batched level passes of the flattened recursion.
-
-    Returns (arrays, offsets (B, nb+1), nb, pad_bucket); per row, buckets
-    are contiguous and in key order, odd local ids are equality buckets,
-    pads sit at the row tail.  Classifier threading matches the 1-D
-    ``partition_passes``: radix carries to level 2 with the consumed-bit
-    shift, learned maps back to tree there.
-    """
-    clf = resolve_classifier(cfg.classifier)
-    rng = jax.random.PRNGKey(cfg.seed)
-    r1, r2 = jax.random.split(rng)
-    with obs.trace("level_pass", level=1, k=levels[0], batched=True):
-        arrays, off1, nb1, pad_bucket = batched_level_pass(
-            arrays, n_real, levels[0], cfg, r1
-        )
-    _obs_level_stats(off1, nb1, pad_bucket, level="1")
-    if len(levels) == 1:
-        return arrays, off1, nb1, pad_bucket
-    with obs.trace("level_pass", level=2, k=levels[1], batched=True, segmented=True):
-        arrays, offsets, nb = batched_segmented_level_pass(
-            arrays, off1, nb1, n_real, levels[1], cfg, r2,
-            classifier="radix" if clf == "radix" else "tree",
-            consumed_bits=int(math.log2(levels[0])),
-        )
-    _obs_level_stats(offsets, nb, None, level="2")
-    return arrays, offsets, nb, None  # pads now sit in odd equality buckets
-
-
-def _sort_padded_batched(
-    arrays: Any, n_real: int, cfg: SortConfig, levels: Sequence[int]
-) -> Any:
-    """Sort padded (B, n_pad, ...) arrays dict, all rows in one trace."""
-    n = arrays["k"].shape[1]
-    W = cfg.base_case
-
-    if not levels:
-        return batched_stable_full_sort(arrays)
-
-    arrays, offsets, nb, pad_bucket = batched_partition_passes(
-        arrays, n_real, cfg, levels
-    )
-
-    fb = batched_segment_ids(offsets, n)
-    violated = batched_bucket_violations(offsets, nb, W, pad_bucket)
-    _obs_base_stats(violated)
-
-    with obs.trace("base_case", W=W, fallback=cfg.fallback, batched=True):
-        if cfg.fallback:
-            return jax.lax.cond(
-                violated,
-                batched_stable_full_sort,
-                lambda a: batched_base_case(a, fb, W),
-                arrays,
-            )
-        return batched_base_case(arrays, fb, W)
+            return jax.lax.cond(violated, fallback, lambda a: run(a, fb), arrays)
+        return run(arrays, fb)
 
 
 def ips4o_sort_batched(
@@ -909,7 +647,8 @@ def ips4o_sort_batched(
     cfg: SortConfig = SortConfig(),
 ):
     """Sort every row of ``keys`` (B, n) independently, ascending, in ONE
-    trace (DESIGN.md §6) — no vmap over the 1-D sort, no python loop.
+    trace (DESIGN.md §6): the 1-D stages under ``jax.vmap``, with one
+    batch-wide fallback.
 
     Optionally permutes a ``values`` pytree (leaves with leading dims
     (B, n)) alongside, row by row.  Same key contract as
@@ -929,9 +668,9 @@ def ips4o_sort_batched(
 
     unit = max(cfg.base_case, cfg.tile)
     with obs.trace("ips4o_sort_batched", B=B, n=n, engine=cfg.engine):
-        arrays = batched_pad_with_sentinel(arrays, unit)
+        arrays = jax.vmap(partial(pad_with_sentinel, unit=unit))(arrays)
         levels = plan_levels(arrays["k"].shape[1], cfg)
-        arrays = _sort_padded_batched(arrays, n, cfg, levels)
+        arrays = _sort_padded(arrays, n, cfg, levels, lift=jax.vmap)
 
     out_k = arrays["k"][:, :n]
     if values is None:
@@ -1068,12 +807,6 @@ def tiebreak_passes(
         )
 
     return out, state["v"]
-
-
-def is4o_sort(keys: jax.Array, values: Any = None, cfg: SortConfig = SortConfig()):
-    """IS4o — the sequential (single-core) instantiation; on TPU a single
-    core runs the same pass pipeline, so this is an alias with one stripe."""
-    return ips4o_sort(keys, values, cfg)
 
 
 def make_sorter(n: int, dtype, cfg: SortConfig = SortConfig(), donate: bool = True):

@@ -52,7 +52,6 @@ from repro import obs
 __all__ = [
     "tile_histogram",
     "stable_partition",
-    "batched_stable_partition",
     "partition_permutation",
     "partition_blocks",
     "ENGINES",
@@ -152,7 +151,8 @@ def stable_partition(
                 boundaries for free.
 
     Both engines produce bit-identical results.  Returns
-    (reordered pytree, offsets (nb+1,)).
+    (reordered pytree, offsets (nb+1,)).  (B, n) rows partition under
+    ``jax.vmap`` of this function (DESIGN.md §6).
     """
     if engine == "pallas":
         from repro.kernels.level_fused import rank_hist
@@ -171,59 +171,6 @@ def stable_partition(
     perm, offsets = partition_permutation(bucket, nb, tile)
     with obs.layer("move"):
         out = jax.tree.map(lambda a: jnp.take(a, perm, axis=0), arrays)
-    return out, offsets
-
-
-def batched_stable_partition(
-    bucket: jax.Array,
-    arrays: Pytree,
-    nb: int,
-    tile: int,
-    engine: str = "xla",
-    *,
-    offsets: Optional[jax.Array] = None,
-    interpret: Optional[bool] = None,
-) -> Tuple[Pytree, jax.Array]:
-    """Per-row stable partition over a leading batch dimension (DESIGN.md §6).
-
-    ``bucket`` is (B, n); every leaf of ``arrays`` is (B, n, ...).  Each row
-    is partitioned independently — elements never cross rows — producing
-    per-row bucket boundaries ``offsets`` (B, nb+1).
-
-    Engines mirror :func:`stable_partition`:
-
-      "xla"     the per-tile-argsort permutation, vmapped over rows (dense
-                jnp ops batch natively);
-      "pallas"  ONE launch of the batch-grid fused rank+histogram kernel
-                (``kernels.level_fused.rank_hist_batched``) — rows are
-                fully independent, no counter resets exist — followed by
-                a flat scatter.  ``offsets`` is ignored on this path (the
-                kernel recomputes identical boundaries for free).
-
-    Both produce the bit-identical per-row stable permutation.
-    """
-    B, n = bucket.shape
-    if engine == "pallas":
-        from repro.kernels.level_fused import rank_hist_batched
-
-        dest, offsets = rank_hist_batched(
-            bucket.astype(jnp.int32), nb=nb, interpret=interpret
-        )
-        # flatten the per-row destinations into one scatter over (B*n, ...)
-        flat_dest = (dest + n * jnp.arange(B, dtype=jnp.int32)[:, None]).reshape(-1)
-
-        def move(a):
-            fa = a.reshape((B * n,) + a.shape[2:])
-            out = jnp.zeros_like(fa).at[flat_dest].set(fa, mode="promise_in_bounds")
-            return out.reshape(a.shape)
-
-        return jax.tree.map(move, arrays), offsets
-    if engine != "xla":
-        raise ValueError(f"unknown partition engine {engine!r}; expected {ENGINES}")
-    perm, offsets = jax.vmap(lambda b: partition_permutation(b, nb, tile))(bucket)
-    out = jax.tree.map(
-        lambda a: jax.vmap(lambda row, p: jnp.take(row, p, axis=0))(a, perm), arrays
-    )
     return out, offsets
 
 

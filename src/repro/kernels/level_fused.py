@@ -27,9 +27,10 @@ tiles in order, stable grouping within a tile — tiling-independent.
 
 Unlike the counting-rank kernel (``dispatch_rank``), nothing here carries
 running counters across the sequential grid: every grid step is
-independent, so the same body serves the batched form (grid (B, tiles))
-with zero reset logic, and a future multi-core stripe split needs no
-cross-step state at all.
+independent, so ``jax.vmap`` of a call is the batched form: Pallas's
+batching rule prepends the row axis to the grid, (B, tiles), with zero
+reset logic, and a future multi-core stripe split needs no cross-step
+state at all.
 
 ``rank_hist`` is the classify-free mode for callers that already hold
 bucket ids (the segmented/composite level pass, ``stable_partition``'s
@@ -54,9 +55,7 @@ from repro.kernels import resolve_interpret
 
 __all__ = [
     "level_fused",
-    "level_fused_batched",
     "rank_hist",
-    "rank_hist_batched",
     "fused_rows",
 ]
 
@@ -190,21 +189,22 @@ def splitter_block(splitters: jax.Array, k: int) -> jax.Array:
 
 
 def _fused_kernel(
-    *refs, k: int, nb: int, rows: int, tiles_per_row: int, n_real: int,
-    classifier: str, consumed: int,
+    *refs, k: int, nb: int, rows: int, n_real: int, classifier: str, consumed: int,
 ):
     if classifier == "radix":
         keys_ref, bucket_ref, rank_ref, hist_ref = refs
         spl_ref = None
     else:
         keys_ref, spl_ref, bucket_ref, rank_ref, hist_ref = refs
-    tile_id = pl.program_id(1) if tiles_per_row else pl.program_id(0)
+    # the tile within the row: under ``jax.vmap`` the batching rule
+    # prepends the row axis to the grid and remaps this axis
+    tile_id = pl.program_id(0)
     keys = keys_ref[...]  # (rows, 128)
     bucket = classify_tile(
         keys, spl_ref, k=k, classifier=classifier, consumed=consumed
     )
-    # in-kernel pad routing: positions >= n_real (within the row, for the
-    # batched grid) belong to the dedicated pad bucket 2k
+    # in-kernel pad routing: positions >= n_real belong to the dedicated
+    # pad bucket 2k
     tile = rows * LANES
     pos = (
         tile_id * tile
@@ -223,8 +223,7 @@ def _close_placement(bucket, rank, hist, nb: int, tile: int):
     """The XLA epilogue: prefix-sum the histogram and place every element.
 
     O(num_tiles * nb) prefix work plus one fused elementwise gather —
-    never a second pass of classify/one-hot over the data.  1-D form;
-    callers vmap it for the batched grid (everything batches natively).
+    never a second pass of classify/one-hot over the data.
     """
     n = bucket.shape[0]
     num_tiles = hist.shape[0]
@@ -288,8 +287,8 @@ def level_fused(
     keys2 = keys.reshape(num_tiles * rows, LANES)
 
     kern = functools.partial(
-        _fused_kernel, k=k, nb=nb, rows=rows, tiles_per_row=0,
-        n_real=n_real, classifier=classifier, consumed=consumed_bits,
+        _fused_kernel, k=k, nb=nb, rows=rows, n_real=n_real,
+        classifier=classifier, consumed=consumed_bits,
     )
     in_specs = [pl.BlockSpec((rows, LANES), lambda i: (i, 0))]
     operands = [keys2]
@@ -316,74 +315,6 @@ def level_fused(
     )(*operands)
     return _close_placement(
         bucket.reshape(n), rank.reshape(n), hist.reshape(num_tiles, nb), nb, tile
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("k", "n_real", "classifier", "consumed_bits", "rows", "interpret"),
-)
-def level_fused_batched(
-    keys: jax.Array,
-    splitters: Optional[jax.Array] = None,
-    *,
-    k: int,
-    n_real: Optional[int] = None,
-    classifier: str = "tree",
-    consumed_bits: int = 0,
-    rows: Optional[int] = None,
-    interpret: Optional[bool] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Per-row fused level pass over ``keys`` (B, n): batch grid dimension
-    (B, num_tiles), row ``b`` classifying against its own splitter block
-    (tree) or the shared extractor (radix).  No cross-step state exists in
-    the fused body, so rows need no counter resets at all.
-
-    Returns (dest (B, n) int32 within each row, offsets (B, nb+1) int32).
-    """
-    interpret = resolve_interpret(interpret)
-    B, n = keys.shape
-    if n_real is None:
-        n_real = n
-    if rows is None:
-        rows = fused_rows(n, keys.dtype.itemsize, k)
-    tile = rows * LANES
-    if not rows or n % tile:
-        raise ValueError(f"n={n} must be a multiple of a rows*{LANES} tile")
-    num_tiles = n // tile
-    nb = 2 * k + 1
-    keys2 = keys.reshape(B * num_tiles * rows, LANES)
-
-    kern = functools.partial(
-        _fused_kernel, k=k, nb=nb, rows=rows, tiles_per_row=num_tiles,
-        n_real=n_real, classifier=classifier, consumed=consumed_bits,
-    )
-    in_specs = [pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0))]
-    operands = [keys2]
-    if classifier != "radix":
-        in_specs.append(pl.BlockSpec((1, k, LANES), lambda b, i: (b, 0, 0)))
-        operands.append(splitter_block(splitters, k))
-
-    bucket, rank, hist = pl.pallas_call(
-        kern,
-        grid=(B, num_tiles),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-            pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-            pl.BlockSpec((1, 1, nb), lambda b, i: (b * num_tiles + i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((B * num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((B * num_tiles, 1, nb), jnp.int32),
-        ],
-        interpret=interpret,
-        name="level_fused_batched",
-    )(*operands)
-    close = jax.vmap(functools.partial(_close_placement, nb=nb, tile=tile))
-    return close(
-        bucket.reshape(B, n), rank.reshape(B, n), hist.reshape(B, num_tiles, nb)
     )
 
 
@@ -440,56 +371,3 @@ def rank_hist(
         nb, tile,
     )
     return dest[:n], offsets
-
-
-@functools.partial(jax.jit, static_argnames=("nb", "rows", "interpret"))
-def rank_hist_batched(
-    bucket: jax.Array,
-    *,
-    nb: int,
-    rows: Optional[int] = None,
-    interpret: Optional[bool] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Per-row fused rank + histogram over bucket ids (B, n) int32.
-
-    Rows are fully independent (no cross-step state), so the batched form
-    is the unbatched kernel over the flattened rows — tiles never straddle
-    rows because each row self-pads to the kernel tile first.
-
-    Returns (dest (B, n) within each row, offsets (B, nb+1)).
-    """
-    interpret = resolve_interpret(interpret)
-    B, n = bucket.shape
-    if rows is None:
-        from repro.launch.roofline import launch_spec
-
-        rows = launch_spec("rank", 4, nb).rows or 8
-    tile = rows * LANES
-    n_pad = -(-n // tile) * tile
-    if n_pad != n:
-        bucket = jnp.concatenate(
-            [bucket, jnp.full((B, n_pad - n), nb, jnp.int32)], axis=1
-        )
-    num_tiles = n_pad // tile
-    bid2 = bucket.reshape(B * num_tiles * rows, LANES)
-
-    rank, hist = pl.pallas_call(
-        functools.partial(_ids_kernel, nb=nb, rows=rows),
-        grid=(B, num_tiles),
-        in_specs=[pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0))],
-        out_specs=[
-            pl.BlockSpec((rows, LANES), lambda b, i: (b * num_tiles + i, 0)),
-            pl.BlockSpec((1, 1, nb), lambda b, i: (b * num_tiles + i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * num_tiles * rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((B * num_tiles, 1, nb), jnp.int32),
-        ],
-        interpret=interpret,
-        name="rank_hist_batched",
-    )(bid2)
-    close = jax.vmap(functools.partial(_close_placement, nb=nb, tile=tile))
-    dest, offsets = close(
-        bucket.reshape(B, n_pad), rank.reshape(B, n_pad), hist.reshape(B, num_tiles, nb)
-    )
-    return dest[:, :n], offsets

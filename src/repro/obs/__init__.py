@@ -37,7 +37,7 @@ Three layers:
   ``summary()`` (human table).
 
 Instrumented call sites: ``ops/sort.py`` and ``core/ips4o.py`` (layer
-scopes on the 1-D sort, per-level spans on the batched path,
+scopes on the sort pipeline, 1-D and batched alike,
 bucket-imbalance / base-case / fallback stats), ``ops/plan.py``
 (plan-cache hit/miss/autotune, classifier races), ``classify/router.py``
 (routing decisions), ``dist/exchange.py`` (re-split rounds, collective

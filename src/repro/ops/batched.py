@@ -3,13 +3,13 @@
 Every hot caller with real traffic has a batch dimension — MoE routing ids
 per layer, the serve scheduler's admission queues, per-shard document
 lengths — and looping the 1-D sort over rows leaves the accelerator idle
-across exactly that dimension.  These entry points run the whole pipeline
-(per-row sample -> batched branchless classify -> per-row stable partition
--> shared base case) over all B rows at once: the Pallas engine launches
-the batch-grid kernels (grid = (B, tiles)), the XLA engine vmaps its dense
-formulation, and the base-case window sorts of all rows fuse into one
-reshape.  Each row's result is bit-identical to the unbatched op on that
-row (``tests/test_batched.py``).
+across exactly that dimension.  These entry points run the one sort
+pipeline (``core.ips4o._sort_padded``) with every per-row stage under
+``jax.vmap``: sample -> classify -> stable partition -> base case over
+all B rows at once, the Pallas kernels on a (B, tiles) grid by Pallas's
+batching rule, and one batch-wide fallback ``cond`` outside the lift.
+Each row's result is bit-identical to the unbatched op on that row
+(``tests/test_batched.py``).
 
 Like ``ops.sort``, keys are bijected through ``ops.keyspace`` first, so
 NaN / -0.0 handling matches the unbatched ops exactly.
@@ -23,20 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.classify import resolve_classifier
-from repro.core.ips4o import (
-    SortConfig,
-    resolve_engine,
-    batched_base_case,
-    batched_bucket_violations,
-    batched_pad_with_sentinel,
-    batched_partition_passes,
-    batched_segment_ids,
-    batched_stable_full_sort,
-    ips4o_sort_batched,
-    plan_levels,
-)
+from repro.core.ips4o import SortConfig, ips4o_sort_batched, resolve_engine
 from repro.ops import keyspace
-from repro.ops.topk import _prefix_limit
+from repro.ops.topk import _smallest
 
 __all__ = [
     "batched_sort",
@@ -157,38 +146,14 @@ def batched_argsort(
 def _batched_smallest(
     enc: jax.Array, kk: int, cfg: SortConfig
 ) -> Tuple[jax.Array, jax.Array]:
-    """Per-row (sorted kk smallest encoded keys, their original indices).
-
-    The batched form of ``ops.topk.smallest_encoded``: same static W-aligned
-    prefix P covers the rank-(kk-1) bucket of *every* row, so the base
-    case runs over [0, P) of each row only.
+    """Per-row (sorted kk smallest encoded keys, their original indices):
+    ``ops.topk``'s partial sort with the stages under ``jax.vmap``.  The
+    static W-aligned prefix P covers the rank-(kk-1) bucket of *every*
+    row, so the base case runs over [0, P) of each row only.
     """
     B, n = enc.shape
     idx = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :], (B, n))
-    arrays = {"k": enc, "v": idx}
-    unit = max(cfg.base_case, cfg.tile)
-    arrays = batched_pad_with_sentinel(arrays, unit)
-    n_pad = arrays["k"].shape[1]
-    W = cfg.base_case
-    levels = plan_levels(n_pad, cfg)
-
-    if not levels:
-        arrays = batched_stable_full_sort(arrays)
-        return arrays["k"][:, :kk], arrays["v"][:, :kk]
-
-    arrays, offsets, nb, pad_bucket = batched_partition_passes(
-        arrays, n, cfg, levels
-    )
-    P = _prefix_limit(kk, W, n_pad)
-    fb = batched_segment_ids(offsets, n_pad)
-    violated = batched_bucket_violations(offsets, nb, W, pad_bucket, limit=P)
-
-    run = lambda a: batched_base_case(a, fb, W, limit=P)
-    if cfg.fallback:
-        arrays = jax.lax.cond(violated, batched_stable_full_sort, run, arrays)
-    else:
-        arrays = run(arrays)
-    return arrays["k"][:, :kk], arrays["v"][:, :kk]
+    return _smallest({"k": enc, "v": idx}, kk, cfg, lift=jax.vmap)
 
 
 def batched_bottomk(

@@ -22,8 +22,7 @@ import jax.numpy as jnp
 
 from repro.core.ips4o import (
     SortConfig,
-    base_case,
-    bucket_violations,
+    base_case_or_fallback,
     pad_with_sentinel,
     segment_ids,
     segmented_level_pass,
@@ -128,19 +127,12 @@ def segmented_sort(
         arrays, off_ext, num_seg_ext, n_pad, k, cfg, rng
     )
 
-    fb = segment_ids(boffs, n_pad)
-    violated = bucket_violations(boffs, nb, W)
     # the composite partition is stable and monotone in segment, so each
     # segment keeps its input index range — fallback can recompute seg ids
     seg = segment_ids(off_ext, n_pad)
-
-    run = lambda a: base_case(a, fb, W)
-    if cfg.fallback:
-        arrays = jax.lax.cond(
-            violated, lambda a: _stable_segmented_sort(a, seg), run, arrays
-        )
-    else:
-        arrays = run(arrays)
+    arrays = base_case_or_fallback(
+        arrays, boffs, nb, None, cfg, lambda a: _stable_segmented_sort(a, seg)
+    )
 
     out = keyspace.decode(arrays["k"][:n], keys.dtype)
     if values is None:

@@ -24,21 +24,19 @@ the complemented keys are the top-k of the originals.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from functools import partial
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.ips4o import (
     SortConfig,
-    base_case,
-    bucket_violations,
+    _per_row,
+    _sort_padded,
     pad_with_sentinel,
-    partition_passes,
     plan_levels,
     replicated,
-    segment_ids,
-    stable_full_sort,
 )
 from repro.ops import keyspace
 
@@ -48,6 +46,21 @@ __all__ = ["topk", "bottomk", "smallest_encoded"]
 def _prefix_limit(k: int, W: int, n_pad: int) -> int:
     """Static W-aligned prefix length covering the bucket of rank k-1."""
     return min(n_pad, -(-(k + W) // W) * W)
+
+
+def _smallest(arrays: Any, kk: int, cfg: SortConfig, lift=_per_row) -> Tuple[Any, Any]:
+    """(sorted kk smallest keys, their payload) of each row of the arrays
+    dict, by the partial sort: the shared pipeline with ``limit = P``.
+    ``lift`` carries the per-row stages over the rows, as in
+    ``core.ips4o._sort_padded``: the identity for (n,), ``jax.vmap`` for
+    (B, n)."""
+    n = arrays["k"].shape[-1]
+    arrays = lift(partial(pad_with_sentinel, unit=max(cfg.base_case, cfg.tile)))(arrays)
+    n_pad = arrays["k"].shape[-1]
+    levels = plan_levels(n_pad, cfg)
+    P = _prefix_limit(kk, cfg.base_case, n_pad)
+    arrays = _sort_padded(arrays, n, cfg, levels, limit=P, lift=lift)
+    return arrays["k"][..., :kk], arrays["v"][..., :kk]
 
 
 def smallest_encoded(
@@ -60,28 +73,7 @@ def smallest_encoded(
     per-shard candidate filter of the distributed rank-k query.
     """
     n = enc.shape[0]
-    arrays = {"k": replicated(enc), "v": jnp.arange(n, dtype=jnp.int32)}
-    unit = max(cfg.base_case, cfg.tile)
-    arrays = pad_with_sentinel(arrays, unit)
-    n_pad = arrays["k"].shape[0]
-    W = cfg.base_case
-    levels = plan_levels(n_pad, cfg)
-
-    if not levels:
-        arrays = stable_full_sort(arrays)
-        return arrays["k"][:kk], arrays["v"][:kk]
-
-    arrays, offsets, nb, pad_bucket = partition_passes(arrays, n, cfg, levels)
-    P = _prefix_limit(kk, W, n_pad)
-    fb = segment_ids(offsets, n_pad)
-    violated = bucket_violations(offsets, nb, W, pad_bucket, limit=P)
-
-    run = lambda a: base_case(a, fb, W, limit=P)
-    if cfg.fallback:
-        arrays = jax.lax.cond(violated, stable_full_sort, run, arrays)
-    else:
-        arrays = run(arrays)
-    return arrays["k"][:kk], arrays["v"][:kk]
+    return _smallest({"k": replicated(enc), "v": jnp.arange(n, dtype=jnp.int32)}, kk, cfg)
 
 
 def bottomk(

@@ -67,6 +67,64 @@ def test_batched_b1_equals_unbatched():
         np.testing.assert_array_equal(b1[0], ref)
 
 
+def _conds(jaxpr):
+    """Every ``cond`` equation of a jaxpr, nested jaxprs included."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _conds(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    yield from _conds(sub)
+
+
+@pytest.mark.parametrize("engine", ["xla", "pallas"])
+def test_batched_one_fallback_cond_outside_the_lift(engine):
+    """The batch lift keeps ONE fallback ``cond`` on a scalar predicate
+    (a ``vmap`` of the cond would be a ``select`` running both branches),
+    and a batch whose first row alone breaks the base case's precondition
+    still sorts every row bit for bit like the 1-D sort of that row.
+
+    One level of k = 16 from 8 sampled keys: at most 9 ranges hold the
+    4,088 unsampled distinct keys of row 0, so one even bucket has more
+    than W/2 = 128 of them; a constant row lands wholly in an equality
+    bucket."""
+    from repro.core.ips4o import (
+        bucket_violations, ips4o_sort, ips4o_sort_batched, partition_passes,
+    )
+
+    cfg = SortConfig(base_case=256, kmax=128, tile=256, max_sample=8, slack=1,
+                     engine=engine)
+    n = 4096
+    levels = plan_levels(n, cfg)
+    assert levels == [16]
+    x = np.stack([np.random.default_rng(0).permutation(n),
+                  np.full(n, 7), np.full(n, -3)]).astype(np.int32)
+    v = np.tile(np.arange(n, dtype=np.int32), (3, 1))
+
+    flags = []
+    for row in x:
+        _, off, nb, pad = partition_passes({"k": jnp.asarray(row)}, n, cfg, levels)
+        flags.append(bool(bucket_violations(off, nb, cfg.base_case, pad)))
+    assert flags == [True, False, False]
+
+    f = lambda k, v: ips4o_sort_batched(k, v, cfg=cfg)
+    conds = list(_conds(jax.make_jaxpr(f)(x, v).jaxpr))
+    assert len(conds) == 1
+    assert conds[0].invars[0].aval.shape == ()
+
+    out_k, out_v = jax.jit(f)(jnp.asarray(x), jnp.asarray(v))
+    for i in range(3):
+        ref_k, ref_v = ips4o_sort(jnp.asarray(x[i]), jnp.asarray(v[i]), cfg=cfg)
+        np.testing.assert_array_equal(np.asarray(out_k[i]), np.asarray(ref_k))
+        np.testing.assert_array_equal(np.asarray(out_v[i]), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(out_v[0]), np.argsort(x[0], kind="stable"))
+
+
 def test_batched_payload_and_argsort():
     x = _rows("TwoDup", _N, np.float32)
     v = jnp.broadcast_to(jnp.arange(_N, dtype=jnp.int32)[None, :], (_B, _N))

@@ -7,12 +7,13 @@ equal the stable counting placement the "xla" engine computes, for every
 classifier mode and every wrapper layer.  Covered here:
 
   * direct kernel parity vs a numpy stable-rank oracle (tree + radix
-    classifiers, in-kernel pad routing, batched grid, ``rank_hist`` on
-    precomputed ids with self-padding);
+    classifiers, in-kernel pad routing, the (B, tiles) grid that
+    ``jax.vmap`` of a call gives, ``rank_hist`` on precomputed ids with
+    self-padding);
   * stack parity over all nine paper distributions x {f32, i32} x
     {single-level, two-level, batched, batched-two-level/segmented} —
-    engine "pallas" vs engine "xla" through ``partition_passes`` /
-    ``batched_partition_passes``, keys AND offsets bit-equal;
+    engine "pallas" vs engine "xla" through ``partition_passes``, alone
+    or under ``jax.vmap``, keys AND offsets bit-equal;
   * u64 keys in a subprocess (x64 must be enabled from interpreter
     startup — see tests/test_classify.py for why);
   * unit tests for the unified :class:`KernelLaunchSpec` every sort
@@ -24,6 +25,7 @@ import sys
 from dataclasses import replace
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -34,8 +36,6 @@ from repro.core import sampling
 from repro.core.ips4o import (
     SortConfig,
     _classify_rows,
-    batched_pad_with_sentinel,
-    batched_partition_passes,
     pad_with_sentinel,
     partition_passes,
     plan_levels,
@@ -44,9 +44,7 @@ from repro.data.distributions import DISTRIBUTIONS, make_input
 from repro.kernels.level_fused import (
     fused_rows,
     level_fused,
-    level_fused_batched,
     rank_hist,
-    rank_hist_batched,
 )
 from repro.launch.roofline import (
     _CLASSIFY_VMEM_FRACTION,
@@ -141,10 +139,14 @@ class TestFusedKernelDirect:
             spls.append(_splitters(kb, k, self.N_REAL, seed=b))
         keys = jnp.stack(rows_keys)
         spl = jnp.stack(spls)
-        dest, off = level_fused_batched(
-            keys, spl, k=k, n_real=self.N_REAL, interpret=True
+        one = lambda kb, sb: level_fused(
+            kb, sb, k=k, n_real=self.N_REAL, interpret=True
         )
+        dest, off = jax.vmap(one)(keys, spl)
         for b in range(B):
+            row_dest, row_off = one(rows_keys[b], spls[b])
+            np.testing.assert_array_equal(np.asarray(dest[b]), np.asarray(row_dest))
+            np.testing.assert_array_equal(np.asarray(off[b]), np.asarray(row_off))
             ids = _oracle_ids(rows_keys[b], spls[b], k, self.N_REAL, "tree")
             want_dest, want_off = _stable_dest(ids, 2 * k + 1)
             np.testing.assert_array_equal(np.asarray(dest[b]), want_dest)
@@ -164,8 +166,12 @@ class TestFusedKernelDirect:
     def test_rank_hist_batched(self):
         nb, B, n = 33, 4, 2500
         ids = np.random.default_rng(1).integers(0, nb, (B, n)).astype(np.int32)
-        dest, off = rank_hist_batched(jnp.asarray(ids), nb=nb, interpret=True)
+        one = lambda ib: rank_hist(ib, nb=nb, interpret=True)
+        dest, off = jax.vmap(one)(jnp.asarray(ids))
         for b in range(B):
+            row_dest, row_off = one(jnp.asarray(ids[b]))
+            np.testing.assert_array_equal(np.asarray(dest[b]), np.asarray(row_dest))
+            np.testing.assert_array_equal(np.asarray(off[b]), np.asarray(row_off))
             want_dest, want_off = _stable_dest(ids[b], nb)
             np.testing.assert_array_equal(np.asarray(dest[b]), want_dest)
             np.testing.assert_array_equal(np.asarray(off[b]), want_off)
@@ -186,11 +192,14 @@ def _passes_1d(x, cfg):
 
 
 def _passes_batched(x, cfg):
-    arrays = batched_pad_with_sentinel(
-        {"k": ops.keyspace.encode(jnp.asarray(x))}, max(cfg.base_case, cfg.tile)
+    unit = max(cfg.base_case, cfg.tile)
+    arrays = jax.vmap(lambda a: pad_with_sentinel(a, unit))(
+        {"k": ops.keyspace.encode(jnp.asarray(x))}
     )
     levels = plan_levels(arrays["k"].shape[1], cfg)
-    out, off, nb, _ = batched_partition_passes(arrays, x.shape[-1], cfg, levels)
+    out, off = jax.vmap(
+        lambda a: partition_passes(a, x.shape[-1], cfg, levels)[:2]
+    )(arrays)
     return np.asarray(out["k"]), np.asarray(off), levels, arrays["k"].shape[1]
 
 

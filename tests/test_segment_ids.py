@@ -13,11 +13,11 @@ import numpy as np
 import pytest
 
 from repro import ops
-from repro.core.ips4o import SortConfig, batched_segment_ids, segment_ids
+from repro.core.ips4o import SortConfig, segment_ids
 
-# ``repro.ops.topk`` is also the name of a function the package exports
-ops_topk = importlib.import_module("repro.ops.topk")
-ops_segmented = importlib.import_module("repro.ops.segmented")
+# the module of the one sort pipeline, whose base case takes the ids of
+# every caller's level offsets (``ops.bottomk``, ``ops.segmented_sort``)
+core_ips4o = importlib.import_module("repro.core.ips4o")
 
 _small_cfg = SortConfig(base_case=1024, kmax=32, tile=256, max_sample=256, slack=4)
 
@@ -71,7 +71,8 @@ def test_batched_segment_ids_rows_differ():
             _random_offsets(np.random.default_rng(4), n, 8),
         ]
     ).astype(np.int32)
-    got = np.asarray(batched_segment_ids(jnp.asarray(rows), n))
+    # (B, nb+1) offsets go through the batch lift of the sort pipeline
+    got = np.asarray(jax.vmap(lambda off: segment_ids(off, n))(jnp.asarray(rows)))
     np.testing.assert_array_equal(got, np.stack([_searched(r, n) for r in rows]))
 
 
@@ -106,7 +107,7 @@ def _bottomk_case(monkeypatch):
     # n a multiple of the window: no pads, so the top buckets are empty
     n, k = 8_192, 3_000
     x = np.random.default_rng(5).integers(0, 40, n).astype(np.int32)
-    seen = _spy_offsets(monkeypatch, ops_topk)
+    seen = _spy_offsets(monkeypatch, core_ips4o)
     v, i = ops.bottomk(jnp.asarray(x), k, cfg=_small_cfg)
     order = np.argsort(x, kind="stable")[:k]
     return seen, [(np.asarray(v), x[order]), (np.asarray(i), order)]
@@ -118,7 +119,7 @@ def _segmented_case(monkeypatch):
     n = 8_192
     offs = np.asarray([0, 1_000, 1_000, 5_000, n, n], np.int32)
     x = np.random.default_rng(6).integers(0, 25, n).astype(np.int32)
-    seen = _spy_offsets(monkeypatch, ops_segmented)
+    seen = _spy_offsets(monkeypatch, core_ips4o)
     ks, vs = ops.segmented_sort(
         jnp.asarray(x),
         jnp.asarray(offs),

@@ -29,12 +29,7 @@ import repro.kernels.merge_path
 from repro import ops
 from repro.core.ips4o import SortConfig
 from repro.kernels.dispatch_rank import dispatch_ranks, partition_ranks
-from repro.kernels.level_fused import (
-    level_fused,
-    level_fused_batched,
-    rank_hist,
-    rank_hist_batched,
-)
+from repro.kernels.level_fused import level_fused, rank_hist
 from repro.kernels.bitonic import bitonic_sort_windows
 from repro.kernels.block_permute import permute_blocks_by_dest
 from repro.kernels.classify import (
@@ -98,8 +93,9 @@ KERNELS = {
         functools.partial(level_fused, k=128, classifier="radix", interpret=False),
         [((N,), jnp.uint32)],
     ),
+    # the (B, tiles) grid is Pallas's batching rule on the 1-D kernel
     "level_fused_batched": (
-        functools.partial(level_fused_batched, k=16, interpret=False),
+        jax.vmap(functools.partial(level_fused, k=16, interpret=False)),
         [((64, 1 << 16), jnp.uint32), ((64, 15), jnp.uint32)],
     ),
     "rank_hist": (
@@ -107,7 +103,7 @@ KERNELS = {
         [((N - 1000,), jnp.int32)],
     ),
     "rank_hist_batched": (
-        functools.partial(rank_hist_batched, nb=1024, interpret=False),
+        jax.vmap(functools.partial(rank_hist, nb=1024, interpret=False)),
         [((8, 1 << 18), jnp.int32)],
     ),
     "dispatch_ranks": (
